@@ -13,8 +13,10 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 
 from benchmarks import paper_figs as pf
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def kernel_microbench(reps: int = 5):
@@ -621,6 +623,7 @@ def main(argv=None) -> int:
                     help="also run the serving benches and write their rows "
                          "as JSON (default path: BENCH_serve.json)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = ([args.bench] if args.bench
              else sorted(BENCHES) if args.full
@@ -629,14 +632,17 @@ def main(argv=None) -> int:
         # an explicit --bench stays a PARTIAL run: only that bench's
         # group is (re)written, the merge below keeps the rest
         names += [n for n in SERVE_BENCHES if n not in names]
-    collected = {}
+    collected, failed = {}, []
     print("bench,name,value")
     for name in names:
         t0 = time.time()
         try:
             rows = BENCHES[name]()
         except Exception as e:                                # noqa: BLE001
+            # report and go on to the next bench; the exit code says so
+            traceback.print_exc()
             print(f"{name},ERROR,{type(e).__name__}: {e}")
+            failed.append(name)
             continue
         for group, key, val in rows:
             print(f"{group},{key},{val:.6g}")
@@ -661,6 +667,9 @@ def main(argv=None) -> int:
             f.write("\n")
         print(f"# wrote {args.json} ({len(serve_groups)} group(s) "
               f"updated)", file=sys.stderr)
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

@@ -1,2 +1,1 @@
-from repro.distributed import compat, compression, pipeline, sharding
-from repro.distributed.compat import shard_map
+from repro.distributed import compression, pipeline, sharding

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quantize import BlockQuantSpec, fake_quant
-from repro.distributed.compat import shard_map
 
 
 # E4M3 codes + E4M3 block scales (two-level): the E8M0 floor rule would map
@@ -105,13 +104,13 @@ def pod_mean_grads(grads, key: jax.Array, mesh: Mesh,
     if cfg is None or not cfg.enabled:
         fn = lambda g: jax.tree_util.tree_map(
             lambda x: jax.lax.pmean(x, "pod"), g)
-        return shard_map(fn, mesh=mesh, in_specs=(specs,),
-                         out_specs=specs, axis_names=manual,
-                         check_vma=False)(grads)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, axis_names=manual,
+                             check_vma=False)(grads)
 
     fn = partial(compressed_psum_mean, axis="pod", spec=cfg.spec,
                  npods=npods)
-    return shard_map(
+    return jax.shard_map(
         lambda g, k: fn(g, k), mesh=mesh,
         in_specs=(specs, P()), out_specs=specs, axis_names=manual,
         check_vma=False)(grads, key)

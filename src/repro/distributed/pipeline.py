@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,7 +100,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x: jax.Array,
         return outs
 
     xs = x.reshape((M, mb) + x.shape[1:])
-    out = shard_map(
+    out = jax.shard_map(
         pipelined, mesh=mesh,
         in_specs=(_stage_specs(stacked_params, axis), P()),
         out_specs=P(), axis_names=frozenset({axis}), check_vma=False,

@@ -1,6 +1,9 @@
 """Arithmetic-only minifloat quantization helpers usable *inside* Pallas
 kernel bodies (no frexp, no exotic dtypes — just bitcasts, shifts, round,
-floor; all supported by Mosaic on TPU and by interpret mode on CPU).
+floor, rotations; all supported by Mosaic on TPU and by interpret mode on
+CPU).  No helper reshapes across the (8, 128) vreg tiling: Mosaic refuses
+to split the lane axis, so block reductions are rotation butterflies and
+lane permutations are 0/1 selection matmuls.
 
 Bit-exact against repro.core.formats (tested in tests/test_kernels.py).
 """
@@ -10,8 +13,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import FloatFormat
+
+LANE = 128      # last dim of a vreg tile; block last dims are multiples
+SUBLANE = 8     # second-to-last dim of a 32-bit vreg tile
 
 
 class FmtParams(NamedTuple):
@@ -58,8 +65,10 @@ def quantize_sr_k(x: jax.Array, p: FmtParams, u: jax.Array) -> jax.Array:
 
 
 def uniform_from_bits_k(rbits: jax.Array) -> jax.Array:
-    """uint32 -> [0,1) float32; same convention as formats.uniform_from_bits."""
-    return (rbits >> 8).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    """uint32 -> [0,1) float32; same convention as formats.uniform_from_bits.
+    (The 24-bit value goes through int32: Mosaic has no uint32 -> f32.)"""
+    return ((rbits >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(2.0 ** -24))
 
 
 def _decode_e2m1_nibble_k(nib: jax.Array) -> jax.Array:
@@ -70,23 +79,95 @@ def _decode_e2m1_nibble_k(nib: jax.Array) -> jax.Array:
     shifts + bitcast, the same toolbox as the rest of this module.
     Subnormals (e==0): value = m * 0.5.
     """
-    n = nib.astype(jnp.uint32)
+    n = nib.astype(jnp.int32)
     sign = jnp.where((n & 0x8) != 0, jnp.float32(-1.0), jnp.float32(1.0))
     e = (n >> 1) & 0x3
     m = n & 0x1
-    vbits = (((e + jnp.uint32(126)) << 23) | (m << 22)).astype(jnp.uint32)
+    vbits = ((e + 126) << 23) | (m << 22)
     normal = jax.lax.bitcast_convert_type(vbits, jnp.float32)
     mag = jnp.where(e == 0, m.astype(jnp.float32) * 0.5, normal)
     return sign * mag
 
 
+def select_matmul_k(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` where one operand is a 0/1 (bool) selection matrix with at
+    most one 1 on each contraction line: a permutation, compaction or
+    repeat on the MXU.  Exact (each output is one product by 1.0; HIGHEST
+    keeps all 24 bits of an f32 operand, a bf16 one needs a single pass)."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    prec = (jax.lax.Precision.HIGHEST if dt == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jnp.dot(a.astype(dt), b.astype(dt),
+                   preferred_element_type=jnp.float32, precision=prec)
+
+
+def block_repeat_k(s: jax.Array, block: int, axis: int) -> jax.Array:
+    """``jnp.repeat(s, block, axis)`` for 2D ``s``: each block scale spread
+    over its ``block`` elements, by a selection matmul."""
+    n = s.shape[axis] * block
+    shape = (n, n // block) if axis == 0 else (n // block, n)
+    elem = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    blk = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - axis) * block
+    rep = (elem >= blk) & (elem < blk + block)
+    return select_matmul_k(rep, s) if axis == 0 else select_matmul_k(s, rep)
+
+
+def block_first_k(x: jax.Array, block: int) -> jax.Array:
+    """``x[:, ::block]`` for 2D ``x`` (the first element of each block along
+    the lanes), by a selection matmul."""
+    n = x.shape[1]
+    elem = jax.lax.broadcasted_iota(jnp.int32, (n, n // block), 0)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (n, n // block), 1)
+    return select_matmul_k(x, elem == blk * block)
+
+
+def pick_tile(dim: int, pref: int, align: int) -> int:
+    """Largest multiple of ``align`` that divides ``dim`` and is <= ``pref``.
+    The whole ``dim`` when it is <= ``pref`` or has no such divisor: a block
+    equal to the array dimension is always legal for Mosaic."""
+    if dim <= pref:
+        return dim
+    t = pref - pref % align
+    while t >= align:
+        if dim % t == 0:
+            return t
+        t -= align
+    return dim
+
+
 def unpack_e2m1_k(packed: jax.Array) -> jax.Array:
-    """uint8 nibble pairs -> f32 E2M1 grid values, interleaved on the last
-    axis (inverse of quantize.pack_e2m1); usable inside Pallas kernels."""
-    lo = _decode_e2m1_nibble_k(packed & 0xF)
-    hi = _decode_e2m1_nibble_k(packed >> 4)
-    stacked = jnp.stack([lo, hi], axis=-1)
-    return stacked.reshape(packed.shape[:-1] + (packed.shape[-1] * 2,))
+    """uint8 nibble pairs (R, C) -> f32 E2M1 grid values (R, 2C) in
+    ``quantize.pack_e2m1``'s interleaved order (low nibble -> column 2j,
+    high nibble -> 2j+1); usable inside Pallas kernels.  The interleave is
+    two selection matmuls in bf16 (grid values are exact there)."""
+    p = packed.astype(jnp.int32)
+    lo = _decode_e2m1_nibble_k(p & 0xF).astype(jnp.bfloat16)
+    hi = _decode_e2m1_nibble_k(p >> 4).astype(jnp.bfloat16)
+    half = p.shape[-1]
+    src = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+    return (select_matmul_k(lo, dst == 2 * src)
+            + select_matmul_k(hi, dst == 2 * src + 1))
+
+
+def block_amax_k(x: jax.Array, block: int, axis: int) -> jax.Array:
+    """|x| max over each run of ``block`` elements along ``axis`` (a power
+    of two dividing that axis), broadcast back to every element of the run.
+
+    An XOR butterfly of rotations (log2(block) steps, partner i ^ s), so
+    the lane/sublane tiling is never reshaped."""
+    if block & (block - 1):
+        raise ValueError(f"block={block} must be a power of two")
+    n = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    m = jnp.abs(x)
+    s = 1
+    while s < block:
+        up = pltpu.roll(m, n - s, axis)      # m[i + s]
+        down = pltpu.roll(m, s, axis)        # m[i - s]
+        m = jnp.maximum(m, jnp.where((idx & s) == 0, up, down))
+        s *= 2
+    return m
 
 
 def e8m0_block_scale_k(absmax: jax.Array, data_emax: int) -> jax.Array:

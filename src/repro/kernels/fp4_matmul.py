@@ -30,7 +30,6 @@ from jax.experimental import pallas as pl
 
 from repro.core.quantize import BlockQuantSpec
 from repro.kernels import common as c
-from repro.kernels.nvfp4_quant import _pick_tile
 
 
 # ---- unfused: pre-quantized operands ----------------------------------------
@@ -45,13 +44,8 @@ def _block_matmul_kernel(ac_ref, as_ref, bc_ref, bs_ref, o_ref, *, block: int):
 
     ac = ac_ref[...].astype(jnp.float32)          # (TM, TK)
     bc = bc_ref[...].astype(jnp.float32)          # (TK, TN)
-    asc = as_ref[...]                             # (TM, TK//B)
-    bsc = bs_ref[...]                              # (TK//B, TN)
-    tm, tk = ac.shape
-    tn = bc.shape[1]
-    nb = tk // block
-    ad = (ac.reshape(tm, nb, block) * asc[:, :, None]).reshape(tm, tk)
-    bd = (bc.reshape(nb, block, tn) * bsc[:, None, :]).reshape(tk, tn)
+    ad = ac * c.block_repeat_k(as_ref[...], block, 1)   # scales (TM, TK//B)
+    bd = bc * c.block_repeat_k(bs_ref[...], block, 0)   # scales (TK//B, TN)
     o_ref[...] += jnp.dot(ad, bd, preferred_element_type=jnp.float32)
 
 
@@ -64,8 +58,9 @@ def block_matmul(a_codes, a_scales, b_codes, b_scales, tscale, *,
     M, K = a_codes.shape
     K2, N = b_codes.shape
     assert K == K2, (a_codes.shape, b_codes.shape)
-    TM, TN = _pick_tile(M, tm), _pick_tile(N, tn)
-    TK = _pick_tile(K, tk, block)
+    TM, TN = c.pick_tile(M, tm, c.SUBLANE), c.pick_tile(N, tn, c.LANE)
+    # the (TM, TK//block) scale tile is lane-aligned too
+    TK = c.pick_tile(K, tk, c.LANE * block)
     grid = (M // TM, N // TN, K // TK)
 
     out = pl.pallas_call(
@@ -87,50 +82,45 @@ def block_matmul(a_codes, a_scales, b_codes, b_scales, tscale, *,
 # ---- fused: quantize raw tiles on the fly, then MMA -------------------------
 
 
-def _quant_tile_along_last(x, rb, tscale, *, block, data_p, scale_p,
-                           scale_is_e8m0, stochastic):
-    """Quantize (R, C) tile with blocks along C; returns dequantized tile
-    (codes*scales, tscale NOT applied — folded into the output epilogue)."""
-    r, ccols = x.shape
-    nb = ccols // block
-    xb = x.reshape(r, nb, block)
-    absmax = jnp.max(jnp.abs(xb), axis=-1)
+def _quant_tile(x, rb, tscale, *, axis, block, data_p, scale_p, scale_is_e8m0,
+                stochastic):
+    """Quantize a tile with blocks along ``axis``; returns the dequantized
+    tile (codes*scales, tscale NOT applied — folded into the epilogue).
+    Elementwise on the tile's own layout: the block absmax is broadcast
+    back by ``block_amax_k``, so no reshape splits the vreg tiling."""
+    absmax = c.block_amax_k(x, block, axis)
     if scale_is_e8m0:
         scales = c.e8m0_block_scale_k(absmax, data_p.emax)
     else:
         scales = c.generic_block_scale_k(absmax, data_p.max, scale_p, tscale)
-    scaled = xb / (scales[:, :, None] * tscale)
+    scaled = x / (scales * tscale)
     if stochastic:
-        u = c.uniform_from_bits_k(rb).reshape(r, nb, block)
-        codes = c.quantize_sr_k(scaled, data_p, u)
+        codes = c.quantize_sr_k(scaled, data_p, c.uniform_from_bits_k(rb))
     else:
         codes = c.quantize_rtn_k(scaled, data_p)
-    return (codes * scales[:, :, None]).reshape(r, ccols)
+    return codes * scales
 
 
-def _quant_tile_along_first(x, rb, tscale, *, block, data_p, scale_p,
-                            scale_is_e8m0, stochastic):
-    """Quantize (R, C) tile with blocks along R (no VREG transposes)."""
-    r, ccols = x.shape
-    nb = r // block
-    xb = x.reshape(nb, block, ccols)
-    absmax = jnp.max(jnp.abs(xb), axis=1)                 # (nb, C)
-    if scale_is_e8m0:
-        scales = c.e8m0_block_scale_k(absmax, data_p.emax)
-    else:
-        scales = c.generic_block_scale_k(absmax, data_p.max, scale_p, tscale)
-    scaled = xb / (scales[:, None, :] * tscale)
-    if stochastic:
-        u = c.uniform_from_bits_k(rb).reshape(nb, block, ccols)
-        codes = c.quantize_sr_k(scaled, data_p, u)
-    else:
-        codes = c.quantize_rtn_k(scaled, data_p)
-    return (codes * scales[:, None, :]).reshape(r, ccols)
+def _quant_params(spec: BlockQuantSpec) -> dict:
+    return dict(data_p=c.FmtParams.of(spec.data),
+                scale_p=c.FmtParams.of(spec.scale),
+                scale_is_e8m0=(spec.scale_fmt == "e8m0"),
+                stochastic=spec.stochastic)
+
+
+def _mxu_dtype(*specs: BlockQuantSpec):
+    """bf16 when every operand's dequantized values (code * block scale)
+    are exact in bf16 — true for E2M1 codes with E4M3 or E8M0 scales — so
+    the MXU runs one bf16 pass with f32 accumulation; else f32."""
+    def sig_bits(s):
+        scale_bits = 0 if s.scale_fmt == "e8m0" else s.scale.man_bits
+        return s.data.man_bits + scale_bits + 2
+    return (jnp.bfloat16 if all(sig_bits(s) <= 8 for s in specs)
+            else jnp.float32)
 
 
 def _fused_kernel(a_ref, b_ref, arb_ref, brb_ref, tsa_ref, tsb_ref, o_ref, *,
-                  block: int, data_p_a, scale_p_a, e8m0_a, sr_a: bool,
-                  data_p_b, scale_p_b, e8m0_b, sr_b: bool):
+                  block: int, qa: dict, qb: dict, mxu_dtype):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -141,14 +131,10 @@ def _fused_kernel(a_ref, b_ref, arb_ref, brb_ref, tsa_ref, tsb_ref, o_ref, *,
     tsb = tsb_ref[0, 0]
     a = a_ref[...].astype(jnp.float32)            # (TM, TK) blocked along TK
     b = b_ref[...].astype(jnp.float32)            # (TK, TN) blocked along TK
-    ad = _quant_tile_along_last(
-        a, arb_ref[...], tsa, block=block, data_p=data_p_a, scale_p=scale_p_a,
-        scale_is_e8m0=e8m0_a, stochastic=sr_a)
-    bd = _quant_tile_along_first(
-        b, brb_ref[...], tsb, block=block, data_p=data_p_b, scale_p=scale_p_b,
-        scale_is_e8m0=e8m0_b, stochastic=sr_b)
-    o_ref[...] += jnp.dot(ad, bd, preferred_element_type=jnp.float32) \
-        * (tsa * tsb)
+    ad = _quant_tile(a, arb_ref[...], tsa, axis=1, block=block, **qa)
+    bd = _quant_tile(b, brb_ref[...], tsb, axis=0, block=block, **qb)
+    o_ref[...] += jnp.dot(ad.astype(mxu_dtype), bd.astype(mxu_dtype),
+                          preferred_element_type=jnp.float32) * (tsa * tsb)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -187,8 +173,8 @@ def fused_quant_matmul(a: jax.Array, b: jax.Array,
     if spec_b.stochastic and (b_rbits is None or b_rbits.shape != b.shape):
         raise ValueError("spec_b stochastic requires b_rbits of b.shape")
 
-    TM, TN = _pick_tile(M, tm), _pick_tile(N, tn)
-    TK = _pick_tile(K, tk, B)
+    TM, TN = c.pick_tile(M, tm, c.SUBLANE), c.pick_tile(N, tn, c.LANE)
+    TK = c.pick_tile(K, tk, c.LANE)
     grid = (M // TM, N // TN, K // TK)
 
     def _rb_spec(stoch, shape_map):
@@ -197,13 +183,8 @@ def fused_quant_matmul(a: jax.Array, b: jax.Array,
         return pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
 
     kernel = functools.partial(
-        _fused_kernel, block=B,
-        data_p_a=c.FmtParams.of(spec_a.data),
-        scale_p_a=c.FmtParams.of(spec_a.scale),
-        e8m0_a=(spec_a.scale_fmt == "e8m0"), sr_a=spec_a.stochastic,
-        data_p_b=c.FmtParams.of(spec_b.data),
-        scale_p_b=c.FmtParams.of(spec_b.scale),
-        e8m0_b=(spec_b.scale_fmt == "e8m0"), sr_b=spec_b.stochastic)
+        _fused_kernel, block=B, qa=_quant_params(spec_a),
+        qb=_quant_params(spec_b), mxu_dtype=_mxu_dtype(spec_a, spec_b))
 
     out = pl.pallas_call(
         kernel,
@@ -227,11 +208,10 @@ def fused_quant_matmul(a: jax.Array, b: jax.Array,
 
 
 def _packed_kernel(a_ref, bp_ref, bs_ref, arb_ref, tsa_ref, tsb_ref, o_ref, *,
-                   block: int, block_b: int, data_p_a, scale_p_a, e8m0_a,
-                   sr_a: bool):
+                   block: int, block_b: int, qa: dict, mxu_dtype):
     """A tile is quantized in VREGs exactly as in ``_fused_kernel``; the B
     tile arrives as nibble-packed E2M1 codes (half the bytes of an int8
-    operand, 1/4 of bf16) + float8 block scales, and is unpacked/dequantized
+    operand, 1/4 of bf16) + block scales, and is unpacked/dequantized
     in VREGs — the decode-path weight stream out of HBM is ~4x smaller."""
     k = pl.program_id(2)
 
@@ -242,16 +222,12 @@ def _packed_kernel(a_ref, bp_ref, bs_ref, arb_ref, tsa_ref, tsb_ref, o_ref, *,
     tsa = tsa_ref[0, 0]
     tsb = tsb_ref[0, 0]
     a = a_ref[...].astype(jnp.float32)            # (TM, TK) blocked along TK
-    ad = _quant_tile_along_last(
-        a, arb_ref[...], tsa, block=block, data_p=data_p_a,
-        scale_p=scale_p_a, scale_is_e8m0=e8m0_a, stochastic=sr_a)
+    ad = _quant_tile(a, arb_ref[...], tsa, axis=1, block=block, **qa)
     bcodes = c.unpack_e2m1_k(bp_ref[...])         # (TK, TN) f32 grid values
     bsc = bs_ref[...].astype(jnp.float32)         # (TK//block_b, TN)
-    tk, tn = bcodes.shape
-    nb = tk // block_b
-    bd = (bcodes.reshape(nb, block_b, tn) * bsc[:, None, :]).reshape(tk, tn)
-    o_ref[...] += jnp.dot(ad, bd, preferred_element_type=jnp.float32) \
-        * (tsa * tsb)
+    bd = bcodes * c.block_repeat_k(bsc, block_b, 0)
+    o_ref[...] += jnp.dot(ad.astype(mxu_dtype), bd.astype(mxu_dtype),
+                          preferred_element_type=jnp.float32) * (tsa * tsb)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -291,16 +267,15 @@ def packed_block_matmul(a: jax.Array, b_packed: jax.Array,
     elif a_rbits is None or a_rbits.shape != a.shape:
         raise ValueError("spec_a stochastic requires a_rbits of a.shape")
 
-    TM = _pick_tile(M, tm)
-    TN = _pick_tile(N, tn, 2)
-    TK = _pick_tile(K, tk, max(B, block_b))
+    TM = c.pick_tile(M, tm, c.SUBLANE)
+    TN = c.pick_tile(N, tn, 2 * c.LANE)          # packed tile: TN/2 lanes
+    # the (TK/block_b, TN) scale tile's rows: float8 tiles hold 32 sublanes
+    TK = c.pick_tile(K, tk, max(c.LANE, 32 * block_b))
     grid = (M // TM, N // TN, K // TK)
 
     kernel = functools.partial(
-        _packed_kernel, block=B, block_b=block_b,
-        data_p_a=c.FmtParams.of(spec_a.data),
-        scale_p_a=c.FmtParams.of(spec_a.scale),
-        e8m0_a=(spec_a.scale_fmt == "e8m0"), sr_a=spec_a.stochastic)
+        _packed_kernel, block=B, block_b=block_b, qa=_quant_params(spec_a),
+        mxu_dtype=_mxu_dtype(spec_a))
 
     rb_spec = (pl.BlockSpec((TM, TK), lambda i, j, k: (i, k))
                if spec_a.stochastic
@@ -327,8 +302,8 @@ def packed_block_matmul(a: jax.Array, b_packed: jax.Array,
 # ---- tensor-parallel form (mesh-native serving) -------------------------------
 #
 # The explicit Megatron decomposition of the packed FQT matmul, written as a
-# shard_map over the serving mesh (distributed/compat.py shim, so it runs on
-# the full supported JAX range and on CPU host-platform device counts):
+# shard_map over the serving mesh (it runs on CPU host-platform device
+# counts too):
 #
 #   column-parallel: W sharded on N (output features) — each device runs a
 #     local packed GEMM on its own nibble-code / block-scale shard; NO
@@ -365,7 +340,6 @@ def tp_fp4_matmul(x, w, *, cfg, mesh, seed=None, parallel: str = "column",
 
     from repro.core import fqt
     from repro.core.quantize import PackedQuantizedTensor
-    from repro.distributed.compat import shard_map
 
     if not isinstance(w, PackedQuantizedTensor) or w.ndim != 2:
         raise ValueError("tp_fp4_matmul needs a 2D PackedQuantizedTensor")
@@ -407,6 +381,6 @@ def tp_fp4_matmul(x, w, *, cfg, mesh, seed=None, parallel: str = "column",
             y = jax.lax.psum(y, tp)
         return y.astype(x.dtype)
 
-    y = shard_map(body, mesh=mesh, in_specs=(x_spec, w_specs),
-                  out_specs=out_spec)(qx, w)
+    y = jax.shard_map(body, mesh=mesh, in_specs=(x_spec, w_specs),
+                      out_specs=out_spec, check_vma=False)(qx, w)
     return y.reshape(lead + (N,))

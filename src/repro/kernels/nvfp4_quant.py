@@ -2,9 +2,10 @@
 
 One pass over the tensor: per 16-element block (along the last axis) compute
 amax -> quantized shared scale (E4M3 RtN or E8M0 floor) -> E2M1 codes
-(RtN or SR with explicit random bits).  HBM -> VMEM tiles via BlockSpec; the
-MXU is not involved (pure VPU work), so tiles are sized for VMEM residency
-and lane alignment (last dim multiples of 128, sublane multiples of 8).
+(RtN or SR with explicit random bits).  HBM -> VMEM tiles via BlockSpec;
+the work is on the VPU (the MXU only compacts the per-element scales to one
+per block), so tiles are sized for VMEM residency and lane alignment (last
+dim multiples of 128, sublane multiples of 8).
 
 On Blackwell this step is fused into the tensor-core data path; on TPU we
 expose it standalone (for cache/checkpoint packing and for the unfused
@@ -28,34 +29,27 @@ def _quant_kernel(x_ref, rbits_ref, ts_ref, codes_ref, scales_ref, *,
                   block: int, data_p: c.FmtParams, scale_p: c.FmtParams,
                   scale_is_e8m0: bool, stochastic: bool):
     x = x_ref[...].astype(jnp.float32)                    # (TM, TK)
-    tm, tk = x.shape
-    nb = tk // block
-    xb = x.reshape(tm, nb, block)
-    absmax = jnp.max(jnp.abs(xb), axis=-1)                # (TM, nb)
+    absmax = c.block_amax_k(x, block, 1)                  # per element
     tscale = ts_ref[0, 0]
     if scale_is_e8m0:
         scales = c.e8m0_block_scale_k(absmax, data_p.emax)
     else:
         scales = c.generic_block_scale_k(absmax, data_p.max, scale_p, tscale)
-    scaled = xb / (scales[:, :, None] * tscale)
+    scaled = x / (scales * tscale)
     if stochastic:
-        u = c.uniform_from_bits_k(rbits_ref[...]).reshape(tm, nb, block)
-        codes = c.quantize_sr_k(scaled, data_p, u)
+        codes = c.quantize_sr_k(scaled, data_p,
+                                c.uniform_from_bits_k(rbits_ref[...]))
     else:
         codes = c.quantize_rtn_k(scaled, data_p)
-    codes_ref[...] = codes.reshape(tm, tk).astype(codes_ref.dtype)
-    scales_ref[...] = scales.astype(scales_ref.dtype)
-
-
-def _pick_tile(dim: int, pref: int, multiple: int = 1) -> int:
-    """Largest divisor of dim that is <= pref and a multiple of `multiple`."""
-    t = min(pref, dim)
-    t -= t % multiple
-    while t > multiple and dim % t != 0:
-        t -= multiple
-    if t <= 0 or dim % t != 0:
-        t = dim
-    return t
+    codes_ref[...] = codes.astype(codes_ref.dtype)
+    # one scale per block: compact each LANE*block-wide chunk of the tile
+    # to LANE scale lanes (a fixed-size selection, whatever TK is)
+    tk = x.shape[1]
+    step = c.LANE * block
+    for lo in range(0, tk, step):
+        hi = min(lo + step, tk)
+        scales_ref[:, lo // block:hi // block] = c.block_first_k(
+            scales[:, lo:hi], block).astype(scales_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "interpret"))
@@ -84,8 +78,10 @@ def block_quantize_pallas(
     if spec.stochastic and (rbits is None or rbits.shape != x.shape):
         raise ValueError("SR requires rbits with the same shape as x")
 
-    TM = _pick_tile(M, 256, 8 if M % 8 == 0 else 1)
-    TK = _pick_tile(K, 2048, B)
+    # the (TM, TK//B) scale tile is lane-aligned: TK is a multiple of
+    # LANE*B or all of K; TM keeps a tile near 512K elements
+    TK = c.pick_tile(K, c.LANE * B, c.LANE * B)
+    TM = c.pick_tile(M, max(c.SUBLANE, (1 << 19) // TK), c.SUBLANE)
     grid = (M // TM, K // TK)
 
     kernel = functools.partial(

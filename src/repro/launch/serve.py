@@ -26,11 +26,12 @@ import numpy as np
 from repro.checkpoint import ckpt
 from repro.configs import get_config
 from repro.core import fqt
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.serve import ContinuousEngine, Engine, Request, ServeConfig
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true")
@@ -108,19 +109,15 @@ def main(argv=None):
                          "bit-identical to an untraced run (implies "
                          "--queue)")
     args = ap.parse_args(argv)
+    if (args.prefix_cache or args.prefill_chunk or args.spec_decode
+            or args.trace) and not args.queue:
+        args.queue = 8          # continuous-engine knobs imply --queue
+    return args
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.smoke()
-    params = registry.init_params(cfg, jax.random.PRNGKey(args.seed))
-    if args.ckpt_dir:
-        step, restored = ckpt.restore_latest(args.ckpt_dir, params)
-        if restored is not None:
-            params = restored
-            print(f"restored step-{step} checkpoint")
 
+def serve_config(args: argparse.Namespace) -> ServeConfig:
     kv_fmt = args.kv_cache_format or ("bf16" if args.bf16 else "nvfp4")
-    scfg = ServeConfig(batch_size=args.batch, max_len=args.max_len,
+    return ServeConfig(batch_size=args.batch, max_len=args.max_len,
                        temperature=args.temperature,
                        kv_cache_format=kv_fmt,
                        page_size=args.page_size, max_slots=args.max_slots,
@@ -131,6 +128,34 @@ def main(argv=None):
                        draft_layers=(args.draft_layers
                                      if args.spec_decode else None),
                        mesh=args.mesh)
+
+
+def queued_requests(cfg, args: argparse.Namespace, rng) -> list:
+    """The ``--queue`` trace: two arrivals per tick, each prompt the
+    shared ``--shared-prefix`` tokens plus ``--prompt-len`` random ones."""
+    shared = rng.integers(0, cfg.vocab_size, args.shared_prefix)
+    return [Request(rid=i,
+                    prompt=np.concatenate(
+                        [shared, rng.integers(0, cfg.vocab_size,
+                                              args.prompt_len)]),
+                    max_new=args.max_new, arrival=i // 2)
+            for i in range(args.queue)]
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.smoke()
+    params = registry.init_params(cfg, jax.random.PRNGKey(args.seed))
+    if args.ckpt_dir:
+        step, restored = ckpt.restore_latest(args.ckpt_dir, params)
+        if restored is not None:
+            params = restored
+            print(f"restored step-{step} checkpoint")
+
+    scfg = serve_config(args)
     if args.mesh:
         from repro.distributed import sharding as shd
         from repro.distributed.specs import (packed_gather_ratio,
@@ -144,10 +169,6 @@ def main(argv=None):
     qcfg = fqt.bf16_config() if args.bf16 else None
     rng = np.random.default_rng(0)
 
-    if (args.prefix_cache or args.prefill_chunk or args.spec_decode
-            or args.trace) and not args.queue:
-        args.queue = 8          # continuous-engine knobs imply --queue
-
     tracer = None
     if args.trace:
         from repro.obs import Tracer
@@ -156,13 +177,7 @@ def main(argv=None):
     if args.queue:
         # continuous batching: staggered arrivals through the scheduler
         eng = ContinuousEngine(cfg, params, scfg, qcfg=qcfg, tracer=tracer)
-        shared = rng.integers(0, cfg.vocab_size, args.shared_prefix)
-        reqs = [Request(rid=i,
-                        prompt=np.concatenate(
-                            [shared, rng.integers(0, cfg.vocab_size,
-                                                  args.prompt_len)]),
-                        max_new=args.max_new, arrival=i // 2)
-                for i in range(args.queue)]
+        reqs = queued_requests(cfg, args, rng)
         t0 = time.perf_counter()
         res = eng.run(reqs)
         dt = time.perf_counter() - t0

@@ -19,6 +19,7 @@ import jax
 from repro.configs import get_config
 from repro.core import fqt, qaf
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw, schedule
 from repro.train import TrainConfig, Trainer, TrainerConfig
 
@@ -32,7 +33,7 @@ QUANT = {
 }
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-350m")
     ap.add_argument("--smoke", action="store_true",
@@ -57,8 +58,11 @@ def main(argv=None):
                          "√3-floor ratios, E4M3 scale saturation/underflow, "
                          "SR/RtN rounding tallies, one entry per log_every "
                          "steps) to PATH — open in Perfetto")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build_trainer(args: argparse.Namespace) -> Trainer:
+    """The Trainer that ``main`` runs, built from parsed arguments."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -85,10 +89,17 @@ def main(argv=None):
         from repro.obs import Tracer
         tracer = Tracer(clock="step", process="train")
 
-    trainer = Trainer(cfg, QUANT[args.quant](), tcfg, run_cfg, data_cfg,
-                      tracer=tracer)
+    return Trainer(cfg, QUANT[args.quant](), tcfg, run_cfg, data_cfg,
+                   tracer=tracer)
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    trainer = build_trainer(args)
+    run_cfg, tracer = trainer.run_cfg, trainer.tracer
     trainer.run(jax.random.PRNGKey(args.seed))
-    if tracer is not None:
+    if args.trace:
         tracer.export(args.trace)
         print(f"trace: {tracer.n_events} events "
               f"(clock=step, every {run_cfg.log_every} steps) -> "

@@ -181,8 +181,11 @@ class Engine:
         self.params = packing.pack_model_params(cfg, params, spec,
                                                 mesh=self.mesh)
 
+        # the weights are an ARGUMENT of every compiled program, never a
+        # closed-over constant: a constant is copied into the executable
+        # (GBs at published widths — host RAM and cache entries blow up)
         self._prefill = jax.jit(self._prefill_impl)
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(3,))
 
     def _replicate(self, *xs):
         """Pin small host-facing arrays (tokens/masks/keys) replicated on
@@ -193,12 +196,12 @@ class Engine:
 
     # ---- compiled kernels --------------------------------------------------
 
-    def _prefill_impl(self, tokens, carry, extras):
-        logits, carry = registry.prefill(self.params, self.cfg, self.qcfg,
+    def _prefill_impl(self, params, tokens, carry, extras):
+        logits, carry = registry.prefill(params, self.cfg, self.qcfg,
                                          tokens, carry, extras=extras)
         return logits, shd.constrain_serve_cache(carry, self.mesh)
 
-    def _decode_impl(self, tokens, done, carry, key):
+    def _decode_impl(self, params, tokens, done, carry, key):
         """One lockstep decode step with ON-DEVICE done/EOS bookkeeping:
         emit = the masked output token for this step, done accumulates the
         EOS mask, and the PRNG chain advances on device — the host only
@@ -207,7 +210,7 @@ class Engine:
         emit = jnp.where(done, eos, tokens)
         done = done | (tokens == eos)
         key, sub = jax.random.split(key)
-        logits, carry = registry.decode_step(self.params, self.cfg,
+        logits, carry = registry.decode_step(params, self.cfg,
                                              self.qcfg, emit[:, None],
                                              carry)
         nxt = _sample(logits[:, -1], sub, self.scfg)
@@ -243,7 +246,7 @@ class Engine:
         carry = shd.place_serve_cache(carry, self.mesh)
         toks = self._replicate(toks)
         extras = extras or {}
-        last_logits, carry = self._prefill(toks, carry, extras)
+        last_logits, carry = self._prefill(self.params, toks, carry, extras)
 
         # PRNG hygiene: split the root key FIRST — the first sampled token
         # uses a child, never the parent of the per-step chain.
@@ -254,7 +257,8 @@ class Engine:
         emitted = []                      # device arrays; no per-step sync
         sync = max(1, scfg.decode_chunk)
         for t in range(max_new):
-            emit, done, nxt, carry, key = self._decode(nxt, done, carry, key)
+            emit, done, nxt, carry, key = self._decode(self.params, nxt, done,
+                                                       carry, key)
             emitted.append(emit)
             # transfer the done mask once per tick, not per token
             if (t + 1) % sync == 0 and bool(np.asarray(done).all()):
@@ -358,13 +362,14 @@ class ContinuousEngine:
             self._draft_cfg = dataclasses.replace(cfg, n_layers=dl)
         self._root = jax.random.PRNGKey(scfg.seed)
 
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(4,))
+        # weights are the first argument of every program (see Engine)
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(5,))
         self._prefill_sfx = jax.jit(self._prefill_suffix_impl,
-                                    donate_argnums=(5,))
+                                    donate_argnums=(6,))
         self._prefill_chk = jax.jit(self._prefill_chunk_impl,
-                                    donate_argnums=(3,))
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
-        self._verify = jax.jit(self._verify_k_impl, donate_argnums=(1,))
+                                    donate_argnums=(4,))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
+        self._verify = jax.jit(self._verify_k_impl, donate_argnums=(2,))
 
     def _replicate(self, *xs):
         """See ``Engine._replicate`` — stable input shardings under the
@@ -388,40 +393,40 @@ class ContinuousEngine:
         slot reuse never replays another request's stream."""
         return jax.random.fold_in(jax.random.fold_in(self._root, rid), step)
 
-    def _prefill_impl(self, tokens, plen, slot, rid, carry, extras):
+    def _prefill_impl(self, params, tokens, plen, slot, rid, carry, extras):
         """Prefill one slot from a right-padded (1, prefill_len) prompt and
         sample that request's first token.  slot/plen/rid are DYNAMIC
         operands — one compiled program serves every admission."""
         logits, carry = registry.prefill_slot(
-            self.params, self.cfg, self.qcfg, tokens, carry, slot, plen,
+            params, self.cfg, self.qcfg, tokens, carry, slot, plen,
             extras=extras)
         tok = _sample(logits, self._request_key(rid, 0), self.scfg)[0]
         tok, margin = self._pin(tok, _greedy_margin(logits)[0])
         return tok, margin, shd.constrain_serve_cache(carry, self.mesh)
 
-    def _prefill_suffix_impl(self, tokens, plen, pfx, slot, rid, carry):
+    def _prefill_suffix_impl(self, params, tokens, plen, pfx, slot, rid,
+                             carry):
         """Warm-prefix prefill: the slot's page row already shares the
         cached prefix pages; write + attend only the SUFFIX of the prompt
         (right-padded (1, prefill_len), dynamic pfx/plen/slot/rid
         operands — one compiled program serves every warm admission)."""
         logits, carry = registry.prefill_suffix(
-            self.params, self.cfg, self.qcfg, tokens, carry, slot, plen,
-            pfx)
+            params, self.cfg, self.qcfg, tokens, carry, slot, plen, pfx)
         tok = _sample(logits, self._request_key(rid, 0), self.scfg)[0]
         tok, margin = self._pin(tok, _greedy_margin(logits)[0])
         return tok, margin, shd.constrain_serve_cache(carry, self.mesh)
 
-    def _prefill_chunk_impl(self, tokens, slot, off, carry):
+    def _prefill_chunk_impl(self, params, tokens, slot, off, carry):
         """Chunked prefill, intermediate chunk: write one FULL
         (1, prefill_chunk) slice of a long prompt into a slot's pages at
         positions [off, off + C) — the fourth jitted program (dynamic
         slot/off operands; no logits, no sampling — the final, possibly
         short chunk reuses the suffix program and samples there)."""
-        carry = registry.prefill_chunk(self.params, self.cfg, self.qcfg,
+        carry = registry.prefill_chunk(params, self.cfg, self.qcfg,
                                        tokens, carry, slot, off)
         return shd.constrain_serve_cache(carry, self.mesh)
 
-    def _decode_impl(self, tokens, carry, rids, steps, active):
+    def _decode_impl(self, params, tokens, carry, rids, steps, active):
         """One token for every slot; per-slot kv_len/q_offset ride inside
         the paged caches (``PagedKVCache.lengths``) as vector state.
 
@@ -432,7 +437,7 @@ class ContinuousEngine:
         prefill the operand is dropped at trace time (write_mask=None),
         keeping the non-chunked program byte-identical to before."""
         mask = active if self.scfg.prefill_chunk is not None else None
-        logits, carry = registry.decode_step(self.params, self.cfg,
+        logits, carry = registry.decode_step(params, self.cfg,
                                              self.qcfg, tokens[:, None],
                                              carry, write_mask=mask)
         lg = logits[:, -1]
@@ -446,7 +451,7 @@ class ContinuousEngine:
         return nxt, margin, steps, shd.constrain_serve_cache(carry,
                                                              self.mesh)
 
-    def _verify_k_impl(self, tokens, carry, rids, steps, active):
+    def _verify_k_impl(self, params, tokens, carry, rids, steps, active):
         """Fifth jitted program — speculative verify-k, static (slots, k)
         shapes with the accepted length as a dynamic OUTPUT, so one
         compile serves every tick whatever each slot accepts.
@@ -475,7 +480,7 @@ class ContinuousEngine:
         scfg = self.scfg
         k = scfg.spec_k
         mask = active if scfg.prefill_chunk is not None else None
-        dparams, dcarry = registry.draft_view(self.params, carry,
+        dparams, dcarry = registry.draft_view(params, carry,
                                               self.draft_layers)
         blk = [tokens]
         tok = tokens
@@ -486,7 +491,7 @@ class ContinuousEngine:
             tok = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
             blk.append(tok)
         blk = jnp.stack(blk, axis=1)                         # (B, k)
-        lg, carry = registry.verify_k(self.params, self.cfg, self.qcfg,
+        lg, carry = registry.verify_k(params, self.cfg, self.qcfg,
                                       blk, carry, write_mask=mask)
         g = jnp.argmax(lg, axis=-1).astype(jnp.int32)        # (B, k)
         margin = _greedy_margin(lg)                          # (B, k)
@@ -707,14 +712,15 @@ class ContinuousEngine:
                     # quantized pages — warm admission is BIT-IDENTICAL to
                     # a cold start of the same prompt, for every page fmt
                     tok, margin, carry = self._prefill_sfx(
-                        jnp.asarray(padded), jnp.asarray(len(req.prompt)),
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray(len(req.prompt)),
                         jnp.asarray(pfx), jnp.asarray(slot),
                         jnp.asarray(req.rid), carry)
                 else:
                     tok, margin, carry = self._prefill(
-                        jnp.asarray(padded), jnp.asarray(len(req.prompt)),
-                        jnp.asarray(slot), jnp.asarray(req.rid), carry,
-                        extras.get(req.rid, {}))
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray(len(req.prompt)), jnp.asarray(slot),
+                        jnp.asarray(req.rid), carry, extras.get(req.rid, {}))
                 steps = steps.at[slot].set(1)
                 pending[slot] = (tok, margin)
                 if req.rid in forced:
@@ -734,13 +740,14 @@ class ContinuousEngine:
                     chunk = np.asarray(req.prompt[start:start + clen],
                                        np.int32)[None]
                     carry = self._prefill_chk(
-                        jnp.asarray(chunk), jnp.asarray(slot),
+                        self.params, jnp.asarray(chunk), jnp.asarray(slot),
                         jnp.asarray(start), carry)
                     continue
                 padded = np.zeros((1, prefill_pad), np.int32)
                 padded[0, :clen] = req.prompt[start:]
                 tok, margin, carry = self._prefill_sfx(
-                    jnp.asarray(padded), jnp.asarray(len(req.prompt)),
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray(len(req.prompt)),
                     jnp.asarray(start), jnp.asarray(slot),
                     jnp.asarray(req.rid), carry)
                 steps = steps.at[slot].set(1)
@@ -786,7 +793,7 @@ class ContinuousEngine:
             if self.spec and active:
                 # -- speculative tick: draft + verify + rollback, one call
                 g, margin, ne_d, nxt, steps, carry = self._verify(
-                    tokens, carry, rids, steps, amask)
+                    self.params, tokens, carry, rids, steps, amask)
                 tokens = nxt
                 em_s = np.asarray(g)                  # (n_slots, k)
                 mg_s = np.asarray(margin)
@@ -799,9 +806,8 @@ class ContinuousEngine:
             else:
                 picks, margs = [], []
                 for _ in range(T):
-                    nxt, margin, steps, carry = self._decode(tokens, carry,
-                                                             rids, steps,
-                                                             amask)
+                    nxt, margin, steps, carry = self._decode(
+                        self.params, tokens, carry, rids, steps, amask)
                     picks.append(nxt)
                     margs.append(margin)
                     tokens = nxt

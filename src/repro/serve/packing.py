@@ -43,6 +43,11 @@ WEIGHT_KEYS = frozenset({
 })
 HEAD_KEYS = frozenset({"lm_head"})
 
+# Compiled, so XLA fuses the quantization of a weight: op by op, each step
+# holds a temporary the size of the f32 weight on the device.
+_pack_leaf = jax.jit(pack_quantize, static_argnames=("spec", "axis",
+                                                     "batch_dims"))
+
 
 def _leaf_name(path) -> str:
     for entry in reversed(path):
@@ -97,8 +102,7 @@ def pack_model_params(cfg: ModelConfig, params: Any,
             name = _leaf_name(path)
             if not _packable(name, leaf, spec, cfg.quantize_lm_head):
                 return leaf
-            return pack_quantize(leaf, spec, axis=-2,
-                                 batch_dims=leaf.ndim - 2)
+            return _pack_leaf(leaf, spec, axis=-2, batch_dims=leaf.ndim - 2)
 
         packed = jax.tree_util.tree_map_with_path(pack, params)
 

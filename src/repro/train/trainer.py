@@ -77,6 +77,11 @@ class Trainer:
         self._step_fn = None
         self._leaf_info = None          # [(path, size)] in grad-leaf order
 
+    @property
+    def step_fn(self):
+        """The jitted train step in use (rebuilt when QAF switches on)."""
+        return self._step_fn
+
     # ---- lifecycle -------------------------------------------------------
 
     def _install_sigterm(self):

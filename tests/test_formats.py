@@ -4,10 +4,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 import ml_dtypes
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # offline container: deterministic shim
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import formats
 from repro.core.formats import (E2M1, E4M3, E5M2, E8M0, E3M4, get_format,

@@ -3,10 +3,7 @@
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # offline container: deterministic shim
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.launch import hlo_cost
 

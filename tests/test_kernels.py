@@ -8,10 +8,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # offline container: deterministic shim
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import fqt
 from repro.core.quantize import BlockQuantSpec, NVFP4, MXFP4, block_quantize

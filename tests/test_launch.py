@@ -80,3 +80,26 @@ def test_decode_carry_structs_all_archs():
         if cfg.sliding_window:
             kv = [x for x in leaves if x.ndim == 4 and x.shape[1] > 1]
             assert all(x.shape[2] <= cfg.sliding_window for x in kv)
+
+
+def test_compile_cache_placement(monkeypatch):
+    """Entry points keep JAX's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says, else at one fixed in-repo path."""
+    import os
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path      # fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

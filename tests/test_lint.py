@@ -9,6 +9,7 @@ Everything here is jax-free (repro.analysis is pure stdlib), so this
 file runs even when the accelerator stack is broken.
 """
 import os
+import shutil
 import subprocess
 import sys
 import textwrap
@@ -313,9 +314,13 @@ def test_empty_baseline_for_prng_and_spec_rules():
 
 def test_seeded_violations_caught_by_whole_repo_run(tmp_path):
     """One scratch file violating all six rules, dropped into the scan
-    tree: the whole-repo run must catch every one of them."""
-    scratch = os.path.join(REPO_ROOT, "src", "repro", "serve",
-                           "_lint_seed_scratch.py")
+    tree: the whole-repo run must catch every one of them.  The tree is a
+    copy, so a lint of the live repo running meanwhile (another test
+    worker) never sees the scratch file."""
+    for d in DEFAULT_SCAN_DIRS:
+        shutil.copytree(os.path.join(REPO_ROOT, d), tmp_path / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    scratch = tmp_path / "src" / "repro" / "serve" / "_lint_seed_scratch.py"
     src = textwrap.dedent("""
         spec = BlockQuantSpec(stochastic=True)
         key = jax.random.PRNGKey(0)
@@ -331,17 +336,13 @@ def test_seeded_violations_caught_by_whole_repo_run(tmp_path):
             tracer.counter("oops")
             return x
         """)
-    try:
-        with open(scratch, "w", encoding="utf-8") as f:
-            f.write(src)
-        findings, _ = lint_paths(root=REPO_ROOT)
-        hit = {f.rule for f in findings
-               if f.path == "src/repro/serve/_lint_seed_scratch.py"}
-        assert hit == set(all_rule_names()), hit
-        new, _ = baseline_diff(findings, load_baseline(BASELINE))
-        assert len(new) >= 6             # none of them baselined away
-    finally:
-        os.unlink(scratch)
+    scratch.write_text(src, encoding="utf-8")
+    findings, _ = lint_paths(root=str(tmp_path))
+    hit = {f.rule for f in findings
+           if f.path == "src/repro/serve/_lint_seed_scratch.py"}
+    assert hit == set(all_rule_names()), hit
+    new, _ = baseline_diff(findings, load_baseline(BASELINE))
+    assert len(new) >= 6             # none of them baselined away
 
 
 # ---- baseline machinery -------------------------------------------------------

@@ -25,10 +25,7 @@ import json
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.obs import (NULL_TRACER, Counters, Tracer, load_trace,
                        validate_events)

@@ -12,10 +12,11 @@ Covers the PR's acceptance criteria:
     <= 0.6 bytes/param on disk for the GEMM weights;
   * regression: fused_quant_matmul honors spec_b's formats (it used to
     silently quantize B with spec_a's data/scale formats);
-  * regression: shard_map compat wrapper importable and callable on this
-    JAX version (jax.shard_map absent on 0.4.x).
+  * regression: jax.shard_map callable with the distributed layer's
+    keyword surface on one device.
 """
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ from repro.core.quantize import (NVFP4, BlockQuantSpec, PackedQuantizedTensor,
                                  block_quantize, fake_quant, pack_e2m1,
                                  pack_quantize, pack_quantized, unpack_e2m1)
 from repro.models import registry
-from repro.serve import Engine, ServeConfig
+from repro.serve import ContinuousEngine, Engine, ServeConfig
 from repro.serve.packing import (pack_model_params, param_count,
                                  weight_store_bytes)
 
@@ -164,6 +165,27 @@ def test_engine_tokens_identical_packed_vs_fake(tiny):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("pack", [True, False])
+def test_engine_weights_are_program_arguments(tiny, pack):
+    """Both engines hand their weights to every compiled program as an
+    argument.  Closed over, they were captured as constants and copied
+    into each executable: at tinyllama-1.1b widths that is 2.2 GB per
+    program, enough to exhaust host memory while compiling."""
+    params = registry.init_params(tiny, jax.random.PRNGKey(0))
+    prompts = [np.random.default_rng(0).integers(0, tiny.vocab_size, 6)]
+    before = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 64 * 1024)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured.*")
+            for cls in (Engine, ContinuousEngine):
+                cls(tiny, params, ServeConfig(batch_size=1, max_len=32),
+                    pack_weights=pack).generate(prompts, max_new=2)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", before)
+
+
 def test_engine_bf16_config_stays_unpacked(tiny):
     params = registry.init_params(tiny, jax.random.PRNGKey(0))
     eng = Engine(tiny, params, ServeConfig(batch_size=2, max_len=64),
@@ -233,16 +255,16 @@ def test_fused_quant_matmul_block_mismatch_raises():
 
 
 def test_shard_map_compat_single_device():
-    """repro.distributed.compat.shard_map works on this JAX version (the
-    jax.shard_map attribute does not exist on 0.4.x)."""
-    from repro.distributed.compat import shard_map
+    """jax.shard_map with the keyword surface the distributed layer calls
+    it with (manual ``axis_names``, ``check_vma=False``) on one device."""
     mesh = jax.make_mesh((1,), ("pipe",))
     from jax.sharding import PartitionSpec as P
 
     def f(x):
         return jax.lax.psum(x, "pipe")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                            axis_names=frozenset({"pipe"}),
-                            check_vma=False))(jnp.ones((4, 4)))
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(),),
+                                out_specs=P(),
+                                axis_names=frozenset({"pipe"}),
+                                check_vma=False))(jnp.ones((4, 4)))
     np.testing.assert_array_equal(np.asarray(out), np.ones((4, 4)))

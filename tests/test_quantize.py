@@ -4,10 +4,7 @@ import pytest
 import jax
 import ml_dtypes
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # offline container: deterministic shim
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import formats
 from repro.core.quantize import (MXFP4, NVFP4, BlockQuantSpec, block_quantize,

@@ -30,10 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.models import registry

@@ -258,3 +258,19 @@ def test_merge_bench_json_is_group_granular():
     # a fresh/unreadable artifact degenerates to just the new groups
     fresh = _merge_bench_json({}, {"traffic": {"x": 1.0}})
     assert fresh["benches"] == {"traffic": {"x": 1.0}}
+
+
+def test_failing_bench_sets_exit_code(monkeypatch, capsys):
+    """A bench that raises is reported as an ERROR row AND makes
+    ``benchmarks.run`` exit non-zero; the other benches still run."""
+    from benchmarks import run
+
+    def boom():
+        raise ValueError("boom")
+
+    monkeypatch.setattr(run, "enable_compile_cache", lambda: None)
+    monkeypatch.setitem(run.BENCHES, "lint", boom)
+    assert run.main(["--bench", "lint"]) == 1
+    assert "lint,ERROR,ValueError: boom" in capsys.readouterr().out
+    monkeypatch.setitem(run.BENCHES, "lint", lambda: [("g", "k", 1.0)])
+    assert run.main(["--bench", "lint"]) == 0

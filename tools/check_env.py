@@ -12,8 +12,7 @@
 
 Default mode prints one line per dependency so a red test run can be
 triaged at a glance instead of letting pytest collection explode on an
-ImportError.  Optional deps have in-repo fallbacks (tests/_hyp.py for
-hypothesis); missing REQUIRED deps exit non-zero.
+ImportError; missing REQUIRED deps exit non-zero.
 
 ``--docs`` scans README.md and docs/*.md fenced code blocks and verifies
 they have not drifted from the code: every ``import``/``from repro...``
@@ -77,10 +76,7 @@ import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-REQUIRED = ("jax", "jaxlib", "ml_dtypes", "numpy", "pytest")
-OPTIONAL = {
-    "hypothesis": "property tests fall back to tests/_hyp.py sweeps",
-}
+REQUIRED = ("jax", "jaxlib", "ml_dtypes", "numpy", "pytest", "hypothesis")
 
 DOC_FILES = ("README.md", "docs/formats.md", "docs/serving.md",
              "docs/lint.md", "docs/observability.md")
@@ -916,19 +912,10 @@ def check_deps() -> int:
             print(f"MISSING  {name}  (required)")
         else:
             print(f"ok       {name} {ver}")
-    for name, fallback in OPTIONAL.items():
-        ver = _probe(name)
-        if ver is None:
-            print(f"absent   {name}  (optional; {fallback})")
-        else:
-            print(f"ok       {name} {ver}")
     try:
         import jax
         print(f"backend  {jax.default_backend()} "
               f"({len(jax.devices())} device(s))")
-        if _probe("jax") and not hasattr(jax, "shard_map"):
-            print("note     jax.shard_map absent -> "
-                  "repro.distributed.compat fallback in use")
     except Exception as e:                                  # noqa: BLE001
         print(f"backend  probe failed: {e}")
     if missing_required:

@@ -494,80 +494,6 @@ def lint_stats_bench():
     return rows
 
 
-def obs_bench():
-    """Observability cost + trace volume: the three-tenant traffic
-    workload (same seeded config as ``traffic``) runs with a live tracer
-    attached, recording how many events / spans / counter series the
-    serve path emits and how many bytes the exported Chrome trace weighs.
-    The two timing rows are host microbenchmarks: recording-tracer
-    emission throughput (events/s) and the disabled ``NULL_TRACER``
-    per-call cost in ns — the "zero when off" claim, measured."""
-    import os
-    import tempfile
-    import time as _time
-
-    import jax
-    from repro.configs import get_config
-    from repro.models import registry
-    from repro.obs import NULL_TRACER, Tracer
-    from repro.serve import (ContinuousEngine, ServeConfig, TenantSpec,
-                             WorkloadConfig, as_requests,
-                             generate_workload)
-
-    cfg = get_config("llama2-60m").smoke()
-    params = registry.init_params(cfg, jax.random.PRNGKey(0))
-    wcfg = WorkloadConfig(tenants=(
-        TenantSpec("chat", rate=0.45, prompt_lens=(6, 12, 20),
-                   prompt_probs=(0.5, 0.3, 0.2), system_prompt_len=16,
-                   max_new=10, deadline_slack=24),
-        TenantSpec("batch", rate=0.15, prompt_lens=(40,), max_new=6,
-                   timeout=12, burst_every=10, burst_size=2),
-        TenantSpec("flaky", rate=0.2, prompt_lens=(60,), max_new=8,
-                   abort_prob=0.6, abort_after=2),
-    ), ticks=24, seed=11, vocab=cfg.vocab_size)
-    reqs = as_requests(generate_workload(wcfg))
-    scfg = ServeConfig(batch_size=4, max_len=96, eos_id=-1,
-                       kv_cache_format="nvfp4", page_size=16,
-                       decode_chunk=8, prefix_cache=True,
-                       prefill_chunk=16)
-    trc = Tracer(clock="tick", process="serve")
-    eng = ContinuousEngine(cfg, params, scfg, tracer=trc)
-    eng.run(reqs)
-
-    fd, path = tempfile.mkstemp(suffix=".json")
-    os.close(fd)
-    try:
-        trc.export(path)
-        trace_bytes = os.path.getsize(path)
-    finally:
-        os.unlink(path)
-
-    # recording-path emission throughput (pure host, no engine)
-    mtrc = Tracer()
-    n = 50_000
-    t0 = _time.perf_counter()
-    for _ in range(n):
-        mtrc.counter("x")
-    emit_per_s = n / (_time.perf_counter() - t0)
-
-    # disabled path: the no-op singleton's per-call cost
-    n = 200_000
-    t0 = _time.perf_counter()
-    for _ in range(n):
-        NULL_TRACER.counter("x")
-    noop_ns = (_time.perf_counter() - t0) / n * 1e9
-
-    return [
-        ("obs", "trace_events", float(trc.n_events)),
-        ("obs", "spans_opened", float(trc.spans_opened)),
-        ("obs", "spans_unclosed", float(len(trc.open_spans()))),
-        ("obs", "counter_series", float(len(trc.counters))),
-        ("obs", "trace_bytes", float(trace_bytes)),
-        ("obs", "emit_events_per_s", emit_per_s),
-        ("obs", "disabled_noop_ns_per_call", noop_ns),
-    ]
-
-
 BENCHES = {
     "fig1": pf.fig1_scale_formats,
     "fig2": pf.fig2_block_sizes,
@@ -585,7 +511,6 @@ BENCHES = {
     "serve_sharded": serve_sharded_bench,
     "traffic": traffic_bench,
     "lint": lint_stats_bench,
-    "obs": obs_bench,
 }
 
 QUICK = ("table2", "fig4", "kernels", "fig5", "fig6", "serve_weights",
@@ -594,10 +519,10 @@ QUICK = ("table2", "fig4", "kernels", "fig5", "fig6", "serve_weights",
 # the serving artifact (BENCH_serve.json): throughput, cache bytes/token,
 # speculative acceptance trajectory, prefix-cache hit rate, sharded-
 # weights wire accounting, the multi-tenant TTFT/TPOT/goodput
-# trajectory, lint trajectory, observability cost/volume
+# trajectory, lint trajectory
 SERVE_BENCHES = ("serve_weights", "kv_cache", "serve_throughput",
                  "spec_decode", "prefix_cache", "serve_sharded", "traffic",
-                 "lint", "obs")
+                 "lint")
 
 
 def _merge_bench_json(existing: dict, new_groups: dict) -> dict:
@@ -653,7 +578,7 @@ def main(argv=None) -> int:
         import os
         serve_groups = {g: v for g, v in collected.items()
                         if g.startswith(("serve", "kv_cache", "prefix",
-                                         "traffic", "lint", "obs"))}
+                                         "traffic", "lint"))}
         existing = {}
         if os.path.exists(args.json):
             try:

@@ -32,9 +32,14 @@ import numpy as np
 
 from repro.core.quantize import (BlockQuantSpec, NVFP4, MXFP4, fake_quant,
                                  PackedQuantizedTensor)
+from repro.obs import scopes
 
 # the six quantization points
 POINTS = ("fwd_w", "fwd_a", "bwd_w", "bwd_g", "upd_g", "upd_a")
+# each point's site number, as _maybe_q and _site_bits take it (it seeds
+# the point's SR bits), mapped to the point's profiler scope
+SITE_SCOPES = tuple(scopes.FQT_Q + p for p in ("fwd_a", "fwd_w", "bwd_g",
+                                               "bwd_w", "upd_a", "upd_g"))
 # the paper's selective-rounding scheme (eqs. 4-6): SR on neural gradients in
 # backward+update GEMMs and on activations in the update GEMM.
 PAPER_SR_POINTS: FrozenSet[str] = frozenset({"bwd_g", "upd_g", "upd_a"})
@@ -119,7 +124,8 @@ def _site_bits(x_shape, seed: jax.Array, site: int) -> jax.Array:
     the jnp path fuses them into the quantize chain (zero HBM traffic) and
     the Pallas path receives the *identical* stream as an operand."""
     from repro.core import formats
-    return formats.counter_bits(_site_seed32(seed, site), x_shape)
+    with jax.named_scope(SITE_SCOPES[site]):
+        return formats.counter_bits(_site_seed32(seed, site), x_shape)
 
 
 def _site_u(seed: jax.Array, site: int, shape) -> jax.Array:
@@ -131,8 +137,9 @@ def _maybe_q(x: jax.Array, spec: Optional[BlockQuantSpec], axis: int,
              seed: jax.Array, site: int) -> jax.Array:
     if spec is None:
         return x
-    u = _site_u(seed, site, x.shape) if spec.stochastic else None
-    return fake_quant(x, spec, axis=axis, u=u)
+    with jax.named_scope(SITE_SCOPES[site]):
+        u = _site_u(seed, site, x.shape) if spec.stochastic else None
+        return fake_quant(x, spec, axis=axis, u=u)
 
 
 def _pallas_gemm(a2d, b2d, spec_a, spec_b, seed, site_a, site_b, out_dtype,
@@ -183,11 +190,13 @@ def _forward(x, w, seed, cfg):
     fwd_w = _if_divisible(cfg.fwd_w, K)
     if _use_pallas(cfg, fwd_a, fwd_w, K):
         x2 = x.reshape(-1, K)
-        y = _pallas_gemm(x2, w, fwd_a, fwd_w, seed, 0, 1, x.dtype)
+        with jax.named_scope(scopes.DOT_FWD):
+            y = _pallas_gemm(x2, w, fwd_a, fwd_w, seed, 0, 1, x.dtype)
         return y.reshape(x.shape[:-1] + (N,))
     qx = _maybe_q(x, fwd_a, axis=-1, seed=seed, site=0)
     qw = _maybe_q(w, fwd_w, axis=0, seed=seed, site=1)
-    y = jnp.matmul(qx, qw, preferred_element_type=jnp.float32)
+    with jax.named_scope(scopes.DOT_FWD):
+        y = jnp.matmul(qx, qw, preferred_element_type=jnp.float32)
     return y.astype(x.dtype)
 
 
@@ -205,13 +214,15 @@ def _bwd_rule(cfg, res, g):
     bwd_w = _if_divisible(cfg.bwd_w, N)
     if _use_pallas(cfg, bwd_g, bwd_w, N):
         g2 = g32.reshape(-1, N)
-        dx = _pallas_gemm(g2, w.T, bwd_g, bwd_w, seed, 2, 3, x.dtype)
+        with jax.named_scope(scopes.DOT_DGRAD):
+            dx = _pallas_gemm(g2, w.T, bwd_g, bwd_w, seed, 2, 3, x.dtype)
         dx = dx.reshape(x.shape)
     else:
         qg_b = _maybe_q(g32, bwd_g, axis=-1, seed=seed, site=2)
         qw_b = _maybe_q(w, bwd_w, axis=1, seed=seed, site=3)  # blocks on N
-        dx = jnp.matmul(qg_b, qw_b.T,
-                        preferred_element_type=jnp.float32).astype(x.dtype)
+        with jax.named_scope(scopes.DOT_DGRAD):
+            dx = jnp.matmul(qg_b, qw_b.T, preferred_element_type=jnp.float32)
+        dx = dx.astype(x.dtype)
 
     # [Update] dW = Q_sr(a)^T @ Q_sr(g) ; contraction over tokens M.
     xf = x.reshape(-1, K)
@@ -227,13 +238,15 @@ def _bwd_rule(cfg, res, g):
     if (_use_pallas(cfg, upd_a, upd_g, M) and upd_a is not None):
         rb_a = (_site_bits((M, K), seed, 4).T
                 if upd_a.stochastic else None)           # align with jnp path
-        dw = _pallas_gemm(xf.T, gf, upd_a, upd_g, seed, 4, 5, w.dtype,
-                          rb_a=rb_a)
+        with jax.named_scope(scopes.DOT_WGRAD):
+            dw = _pallas_gemm(xf.T, gf, upd_a, upd_g, seed, 4, 5, w.dtype,
+                              rb_a=rb_a)
     else:
         qx_u = _maybe_q(xf, upd_a, axis=0, seed=seed, site=4)
         qg_u = _maybe_q(gf, upd_g, axis=0, seed=seed, site=5)
-        dw = jnp.matmul(qx_u.T, qg_u,
-                        preferred_element_type=jnp.float32).astype(w.dtype)
+        with jax.named_scope(scopes.DOT_WGRAD):
+            dw = jnp.matmul(qx_u.T, qg_u, preferred_element_type=jnp.float32)
+        dw = dw.astype(w.dtype)
 
     return dx, dw, _float0_zero(res[2])
 
@@ -260,11 +273,14 @@ def _packed_forward(x: jax.Array, w: PackedQuantizedTensor, seed: jax.Array,
         rb = (_site_bits(x.shape, seed, 0).reshape(-1, K)
               if fwd_a.stochastic else None)
         x2 = x.reshape(-1, K)
-        y = kops.packed_block_matmul(x2, w, fwd_a, a_rbits=rb,
-                                     out_dtype=x.dtype)
+        with jax.named_scope(scopes.DOT_FWD):
+            y = kops.packed_block_matmul(x2, w, fwd_a, a_rbits=rb,
+                                         out_dtype=x.dtype)
         return y.reshape(x.shape[:-1] + (N,))
     qx = _maybe_q(x, fwd_a, axis=-1, seed=seed, site=0)
-    y = jnp.matmul(qx, w.dequant(), preferred_element_type=jnp.float32)
+    wd = w.dequant()
+    with jax.named_scope(scopes.DOT_FWD):
+        y = jnp.matmul(qx, wd, preferred_element_type=jnp.float32)
     return y.astype(x.dtype)
 
 

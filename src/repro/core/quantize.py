@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core import formats
 from repro.core.formats import FloatFormat, get_format
+from repro.obs import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -288,11 +289,13 @@ class PackedQuantizedTensor:
         """codes * block_scales * tscale, bit-identical to the fake-quant
         (QuantizedTensor) reconstruction of the same tensor."""
         dt = self.dtype
-        codes = unpack_e2m1(self.packed, dtype=dt)
-        s = jnp.repeat(self.scales.astype(dt), self.block, axis=self.axis)
-        t = self.tscale.reshape(
-            self.tscale.shape + (1,) * (codes.ndim - self.tscale.ndim))
-        return (codes * s * t).astype(dt)
+        with jax.named_scope(scopes.PACKED_DEQUANT):
+            codes = unpack_e2m1(self.packed, dtype=dt)
+            s = jnp.repeat(self.scales.astype(dt), self.block,
+                           axis=self.axis)
+            t = self.tscale.reshape(
+                self.tscale.shape + (1,) * (codes.ndim - self.tscale.ndim))
+            return (codes * s * t).astype(dt)
 
 
 jax.tree_util.register_dataclass(

@@ -9,7 +9,7 @@ the three weight GEMMs; same choice as the FP8 FQT line of work — DESIGN.md
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Optional, Tuple
 
 import jax
@@ -19,6 +19,20 @@ import numpy as np
 from repro.core import fqt
 from repro.core.fqt import QuantConfig
 from repro.distributed.sharding import constrain
+from repro.obs import scopes
+
+
+def _scoped(name: str):
+    """Trace a method under ``jax.named_scope(name)`` (HLO metadata only;
+    a fresh scope per call, so nested and concurrent traces are safe)."""
+    def wrap(f):
+        @wraps(f)
+        def inner(*args, **kw):
+            with jax.named_scope(name):
+                return f(*args, **kw)
+        return inner
+    return wrap
+
 
 # A large-but-finite mask value: keeps fully-masked rows NaN-free without
 # inf-inf arithmetic anywhere.
@@ -280,6 +294,7 @@ def _flash_bwd_rule(causal, window, qc, kc, res, dout):
 _attn_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@_scoped(scopes.ATTN)
 def attention_core(q, k, v, *, qpos, kpos, causal=True,
                    window: Optional[int] = None, chunk: int = 1024,
                    kv_len: Optional[jax.Array] = None) -> jax.Array:
@@ -530,6 +545,7 @@ class PagedKVCache:
                              f"capacity {self.buf}")
         return self.write_prompt_at(slot, k, v, 0, plen)
 
+    @_scoped(scopes.KV_WRITE)
     def write_prompt_at(self, slot, k: jax.Array, v: jax.Array, start,
                         plen) -> "PagedKVCache":
         """Suffix prefill (shared-prefix admission): write a fresh
@@ -557,6 +573,7 @@ class PagedKVCache:
             self.lengths.at[slot].set(jnp.asarray(plen, jnp.int32)),
             self.fmt, self.block, self.page_size)
 
+    @_scoped(scopes.KV_WRITE)
     def write_token(self, k: jax.Array, v: jax.Array,
                     mask: Optional[jax.Array] = None) -> "PagedKVCache":
         """Batched decode write: one (B, 1, KVH, D) token per slot at each
@@ -592,6 +609,7 @@ class PagedKVCache:
             self.page_table, self.lengths + step,
             self.fmt, self.block, self.page_size)
 
+    @_scoped(scopes.KV_WRITE)
     def write_tokens(self, k: jax.Array, v: jax.Array,
                      mask: Optional[jax.Array] = None) -> "PagedKVCache":
         """Batched multi-token write: S tokens per slot at each slot's own
@@ -652,6 +670,7 @@ class PagedKVCache:
 
     # ---- reads ----------------------------------------------------------
 
+    @_scoped(scopes.KV_READ)
     def gather_slots(self):
         """Gather the logical (B, buf, KVH, ·) packed views through the
         page table (the jnp mirror of the Pallas kernel's per-page DMA)."""
@@ -664,6 +683,7 @@ class PagedKVCache:
         return (g(self.k_codes), g(self.k_scales),
                 g(self.v_codes), g(self.v_scales))
 
+    @_scoped(scopes.KV_READ)
     def gather_slot(self, slot):
         """ONE slot's logical (1, buf, KVH, ·) packed views (``slot`` may
         be traced) — the read side of suffix prefill, which attends a
@@ -679,6 +699,7 @@ class PagedKVCache:
         return (g(self.k_codes), g(self.k_scales),
                 g(self.v_codes), g(self.v_scales))
 
+    @_scoped(scopes.KV_READ)
     def dequant(self, dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
         """Full logical (B, buf, KVH, D) reconstruction — test oracle."""
         kc, ks, vc, vs = self.gather_slots()
@@ -724,6 +745,7 @@ def make_kv_cache(batch: int, buf: int, n_kv: int, hd: int,
     return PackedKVCache.init(batch, buf, n_kv, hd, fmt=kv_format)
 
 
+@_scoped(scopes.ATTN)
 def _attn_decode_fused(q, k_codes, k_scales, v_codes, v_scales, fmt: str,
                        block: int, *, qpos, kpos, causal, window, kv_len,
                        chunk: int = 1024) -> jax.Array:
@@ -764,8 +786,9 @@ def _attn_decode_fused(q, k_codes, k_scales, v_codes, v_scales, fmt: str,
     def kv_step(carry, xs):
         m, l, acc = carry                                  # (B,KVH,G,Sq[,D])
         kc_, ks_, vc_, vs_, kp = xs
-        ki = _kv_dequant_any(kc_, ks_, fmt, block, jnp.float32)
-        vi = _kv_dequant_any(vc_, vs_, fmt, block, jnp.float32)
+        with jax.named_scope(scopes.KV_READ):
+            ki = _kv_dequant_any(kc_, ks_, fmt, block, jnp.float32)
+            vi = _kv_dequant_any(vc_, vs_, fmt, block, jnp.float32)
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, ki) * scale
         mask = jnp.ones((B, Sq, kc), bool)
         if causal:

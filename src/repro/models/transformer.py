@@ -20,6 +20,7 @@ from repro.models.config import ModelConfig
 from repro.models.layers import (QCtx, attn_apply, attn_params, dense_init,
                                  embed_init, make_kv_cache, mlp_apply,
                                  mlp_params, rmsnorm)
+from repro.obs import scopes
 
 _SEED_STRIDE = jnp.uint32(0x9E3779B9)
 
@@ -98,7 +99,8 @@ def apply_layers(params, cfg: ModelConfig, qcfg: QuantConfig, x, seed, *,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
     xs = (params["layers"], seeds, caches)
-    x, (new_caches, auxes) = jax.lax.scan(body, x, xs)
+    with jax.named_scope(scopes.LAYER_SCAN):
+        x, (new_caches, auxes) = jax.lax.scan(body, x, xs)
     return x, new_caches, jnp.sum(auxes)
 
 

@@ -189,7 +189,7 @@ class Tracer:
         self.counters = Counters()
         self.gauges: Dict[str, float] = {}
         self._now = 0
-        self._events: List[dict] = []
+        self._events: List[tuple] = []
         self._meta: List[dict] = []
         self._tids: Dict[str, int] = {}
         self._open: Dict[Tuple[str, str], int] = {}
@@ -222,17 +222,25 @@ class Tracer:
 
     def _emit(self, ph: str, track: str, name: str, ts: Optional[int],
               args: Dict[str, Any]) -> None:
-        if self.wall:
-            args = dict(args)
-            args["wall"] = time.perf_counter()
-        ev = {"name": name, "ph": ph,
-              "ts": self._now if ts is None else int(ts),
-              "pid": 1, "tid": self._tid(track)}
+        # one tuple per event, made into a trace-event dict only when read
+        # (``trace_events``): on the engine's per-tick path an event is one
+        # small allocation
+        self._events.append(
+            (ph, name, self._now if ts is None else int(ts),
+             self._tids.get(track) or self._tid(track), args or None,
+             time.perf_counter() if self.wall else None))
+
+    @staticmethod
+    def _event(rec) -> dict:
+        ph, name, ts, tid, args, wall = rec
+        ev = {"name": name, "ph": ph, "ts": ts, "pid": 1, "tid": tid}
+        if wall is not None:
+            args = dict(args or (), wall=wall)
         if args:
             ev["args"] = args
         if ph == "i":
             ev["s"] = "t"        # instant scope: thread
-        self._events.append(ev)
+        return ev
 
     def begin(self, track: str, name: str, ts: Optional[int] = None,
               **args: Any) -> None:
@@ -301,7 +309,7 @@ class Tracer:
 
     def trace_events(self) -> List[dict]:
         """All events (metadata first) in Chrome trace-event dict form."""
-        return self._meta + self._events
+        return self._meta + [self._event(e) for e in self._events]
 
     def export(self, path: str) -> str:
         """Write Perfetto/chrome://tracing-loadable JSON; returns ``path``."""

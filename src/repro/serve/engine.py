@@ -66,10 +66,14 @@ from repro.distributed import sharding as shd
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.models.layers import TRASH_PAGE, PagedKVCache
+from repro.obs.scopes import ENGINE_TRACK, PHASES, TICK
 from repro.obs.trace import NULL_TRACER
 from repro.serve import packing
 from repro.serve.metrics import MetricsRecorder
 from repro.serve.scheduler import Request, Scheduler
+
+# the phases of a tick, in the order ContinuousEngine.run opens them
+EXPIRE, ADMIT, PREFILL_CHUNK, PAGES, DECODE, SYNC, COMMIT = PHASES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -675,12 +679,31 @@ class ContinuousEngine:
             for rec in jit_progs:
                 rec[2] = rec[1]._cache_size()
 
+        # the tick span holds one span per phase, in PHASES order.  With a
+        # live tracer each also opens a jax.profiler.TraceAnnotation
+        # "engine.<name>", so a profiler trace shows what the host did while
+        # the device sat idle (inactive, one costs under a microsecond)
+        mirrored = []                       # open annotations, innermost last
+
+        def begin(name):
+            trc.begin(ENGINE_TRACK, name)
+            if trc.enabled:
+                mirrored.append(jax.profiler.TraceAnnotation(
+                    f"{ENGINE_TRACK}.{name}"))
+                mirrored[-1].__enter__()
+
+        def end(name):
+            if mirrored:
+                mirrored.pop().__exit__(None, None, None)
+            trc.end(ENGINE_TRACK, name)
+
         tick = 0
         while sched.has_work():
             trc.set_time(tick)
-            trc.begin("engine", "tick")
+            begin(TICK)
             # -- lifecycle: hard aborts/timeouts due NOW fire before any
             # admission or prefill/decode work is issued this tick
+            begin(EXPIRE)
             for slot, rid, stage, reason in sched.expire(tick):
                 met.cancelled(rid, tick, stage, reason)
                 if slot is not None:        # was on-device: park its row
@@ -689,12 +712,14 @@ class ContinuousEngine:
                     slot_rid[slot] = None
                     pending.pop(slot, None)
                     slot_fed.pop(slot, None)
+            end(EXPIRE)
 
             # -- admissions (host): pages + slot, then ONE prefill program
             # (warm shared-prefix admissions run the SUFFIX program; a
             # later admission in the same batch may share pages a prior
             # one writes, so prefills run strictly in placed order).
             # Chunked mode defers ALL prompt writes to prefill_work below.
+            begin(ADMIT)
             for slot, req, row, pfx in sched.admit(tick):
                 carry = self._set_page_row(carry, slot, row)
                 slot_rid[slot] = req.rid
@@ -728,6 +753,7 @@ class ContinuousEngine:
                     tokens = tokens.at[slot].set(int(forced[req.rid][0]))
                 else:
                     tokens = tokens.at[slot].set(tok)
+            end(ADMIT)
 
             # -- chunked prefill: at most ONE chunk per mid-prefill slot
             # per tick, interleaved with this tick's decode.  Chunks
@@ -735,6 +761,7 @@ class ContinuousEngine:
             # (short) chunk — which reuses the suffix program, writes the
             # tail rows and samples the first token — produces streams
             # BIT-IDENTICAL to an unchunked admission of the same prompt.
+            begin(PREFILL_CHUNK)
             for slot, req, start, clen, last in sched.prefill_work(tick):
                 if not last:
                     chunk = np.asarray(req.prompt[start:start + clen],
@@ -757,10 +784,12 @@ class ContinuousEngine:
                     tokens = tokens.at[slot].set(int(forced[req.rid][0]))
                 else:
                     tokens = tokens.at[slot].set(tok)
+            end(PREFILL_CHUNK)
 
             # -- decode tick: no host transfer inside the loop.  Slots
             # still mid-prefill neither emit nor commit (their cache
             # writes are masked to the trash page with frozen lengths).
+            begin(PAGES)
             active = sched.decoding_slots()
             if self.spec:
                 # one verify pass per tick; pages must cover the k
@@ -786,6 +815,8 @@ class ContinuousEngine:
                 pending.pop(slot, None)
                 slot_fed.pop(slot, None)
             active = [s for s in active if s not in preempted]
+            end(PAGES)
+            begin(DECODE)
             amask = np.zeros((self.n_slots,), bool)
             amask[active] = True
             amask = self._replicate(jnp.asarray(amask))
@@ -795,15 +826,7 @@ class ContinuousEngine:
                 g, margin, ne_d, nxt, steps, carry = self._verify(
                     self.params, tokens, carry, rids, steps, amask)
                 tokens = nxt
-                em_s = np.asarray(g)                  # (n_slots, k)
-                mg_s = np.asarray(margin)
-                ne = np.asarray(ne_d)
-                em = em_s.T                           # commit reads [:, slot]
-                mg = mg_s.T
-            elif self.spec:
-                em = np.zeros((0, self.n_slots), np.int32)
-                mg = np.zeros((0, self.n_slots), np.float32)
-            else:
+            elif not self.spec:
                 picks, margs = [], []
                 for _ in range(T):
                     nxt, margin, steps, carry = self._decode(
@@ -816,8 +839,20 @@ class ContinuousEngine:
                         nxt_idx = min(idx + 1, len(stream) - 1)
                         tokens = tokens.at[slot].set(int(stream[nxt_idx]))
                         slot_fed[slot] = nxt_idx
+            end(DECODE)
 
-                # ONE host sync per tick: emitted picks + margins + firsts
+            # ONE host sync per tick: emitted picks + margins + firsts
+            begin(SYNC)
+            if self.spec and active:
+                em_s = np.asarray(g)                  # (n_slots, k)
+                mg_s = np.asarray(margin)
+                ne = np.asarray(ne_d)
+                em = em_s.T                           # commit reads [:, slot]
+                mg = mg_s.T
+            elif self.spec:
+                em = np.zeros((0, self.n_slots), np.int32)
+                mg = np.zeros((0, self.n_slots), np.float32)
+            else:
                 em = (np.asarray(jnp.stack(picks, 0)) if picks
                       else np.zeros((0, self.n_slots), np.int32))
                 mg = (np.asarray(jnp.stack(margs, 0)) if margs
@@ -827,6 +862,9 @@ class ContinuousEngine:
                 np.asarray(jnp.stack([pending[s][0] for s in first_slots])),
                 np.asarray(jnp.stack([pending[s][1] for s in first_slots])))))
             pending.clear()
+            end(SYNC)
+
+            begin(COMMIT)
             emitted_counts = []
             for slot in active:
                 rid = slot_rid[slot]
@@ -861,11 +899,12 @@ class ContinuousEngine:
                 for rec in jit_progs:
                     n = rec[1]._cache_size()
                     if n != rec[2]:
-                        trc.instant("engine", "jit_compile", program=rec[0],
+                        trc.instant(ENGINE_TRACK, "jit_compile", program=rec[0],
                                     cache_size=n)
                         trc.counter("jit_compiles", n - rec[2])
                         rec[2] = n
-            trc.end("engine", "tick")
+            end(COMMIT)
+            end(TICK)
             tick += 1
 
         self.margins = {rid: np.asarray(ms, np.float32)

@@ -28,6 +28,7 @@ from repro.distributed import compression as comp
 from repro.distributed import sharding as shd
 from repro.models import registry
 from repro.models.config import ModelConfig
+from repro.obs import scopes
 from repro.optim import adamw, schedule
 
 
@@ -108,14 +109,17 @@ def make_train_step(cfg: ModelConfig, qcfg: fqt.QuantConfig,
         # §4 monitor: ‖∇L‖ / (σ_q √d) vs √3
         gnorm = adamw.global_norm(grads)
         if tcfg.probe_sigma:
-            sigma_q = _estimate_sigma_q(grads, step, tcfg.sigma_spec)
+            with jax.named_scope(scopes.SIGMA_PROBE):
+                sigma_q = _estimate_sigma_q(grads, step, tcfg.sigma_spec)
         else:
             sigma_q = state.thr.sigma_q
         dd = sum(x.size for x in jax.tree.leaves(grads))
         thr_state = threshold.update(state.thr, gnorm, dd, sigma_q, tcfg.thr)
 
         lr = schedule.lr_at(step, tcfg.sched)
-        params, opt, opt_metrics = adamw.apply(grads, state.opt, tcfg.opt, lr)
+        with jax.named_scope(scopes.OPTIM):
+            params, opt, opt_metrics = adamw.apply(grads, state.opt,
+                                                   tcfg.opt, lr)
 
         metrics = {
             "loss": loss.astype(jnp.float32),

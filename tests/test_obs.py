@@ -28,7 +28,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.obs import (NULL_TRACER, Counters, Tracer, load_trace,
-                       validate_events)
+                       scopes, validate_events)
 from repro.serve.metrics import MetricsRecorder
 from repro.serve.scheduler import Request, Scheduler
 
@@ -251,7 +251,9 @@ def _requests(cfg, max_new=10):
 @pytest.mark.parametrize("fmt", FMTS)
 def test_tracer_on_off_bit_identical_full_compose(fmt):
     """Speculative decoding + chunked prefill + prefix cache, with and
-    without a tracer: identical tokens, identical jit-cache guards."""
+    without a tracer (wall stamps and the profiler mirror on; the device
+    scopes are always in place): identical tokens, identical jit-cache
+    guards."""
     from repro.serve import ContinuousEngine, ServeConfig
     cfg, params = _tiny()
 
@@ -265,7 +267,7 @@ def test_tracer_on_off_bit_identical_full_compose(fmt):
         _BASELINE[fmt] = ContinuousEngine(cfg, params,
                                           scfg()).run(_requests(cfg))
     want = _BASELINE[fmt]
-    trc = Tracer(clock="tick")
+    trc = Tracer(clock="tick", wall=True)
     eng = ContinuousEngine(cfg, params, scfg(), tracer=trc)
     res = eng.run(_requests(cfg))
     for rid in sorted(want):
@@ -283,6 +285,112 @@ def test_tracer_on_off_bit_identical_full_compose(fmt):
     names = {e["name"] for e in trc.trace_events()}
     assert {"request", "tick", "jit_compile", "first_token"} <= names
     assert validate_events(trc.trace_events()) == []
+    # every tick holds each phase once, in order
+    engine = [e["name"] for e in trc.trace_events()
+              if e["ph"] == "B" and e["tid"] == trc._tids[scopes.ENGINE_TRACK]]
+    per_tick = 1 + len(scopes.PHASES)
+    assert len(engine) % per_tick == 0
+    for i in range(0, len(engine), per_tick):
+        assert tuple(engine[i:i + per_tick]) == (scopes.TICK,) + scopes.PHASES
+
+
+def test_tracer_stays_jax_free():
+    """The tracer never imports jax (check_env --obs runs without an
+    accelerator stack): the engine, which has jax, opens the profiler
+    annotations beside its spans."""
+    import subprocess
+    import sys
+    code = ("import sys; from repro.obs.trace import Tracer; "
+            "t = Tracer(wall=True); t.begin('engine', 'tick'); "
+            "t.end('engine', 'tick'); t.trace_events(); "
+            "assert 'jax' not in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True)
+
+
+# ---- device scopes: every site named in the programs' HLO ---------------------
+
+
+def _scope_tokens(lowered):
+    """Every name-stack element of the lowered program's op_name metadata
+    (transform wrappers such as ``transpose(jvp(x))`` split off)."""
+    import re
+    txt = lowered.as_text(dialect="hlo", debug_info=True)
+    toks = set()
+    for name in re.findall(r'op_name="([^"]*)"', txt):
+        toks.update(re.split(r"[/()]", name))
+    return toks
+
+
+def _train_step_args():
+    import jax
+    import jax.numpy as jnp
+    from repro.core import fqt
+    from repro.train import step as st
+    cfg, _ = _tiny()
+    tcfg = st.TrainConfig()
+    state = jax.eval_shape(lambda: st.init_state(cfg, tcfg,
+                                                 jax.random.PRNGKey(0)))
+    # 33 tokens: 32 after the next-token shift, so every token count is a
+    # multiple of the block and the update GEMM quantizes too
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+    return st.make_train_step(cfg, fqt.nvfp4_paper_config(), tcfg), \
+        (state, batch)
+
+
+def _decode_args():
+    import jax.numpy as jnp
+    from repro.models import registry
+    from repro.serve import ContinuousEngine, ServeConfig
+    cfg, params = _tiny()
+    scfg = ServeConfig(batch_size=2, max_len=64, eos_id=NO_EOS,
+                       kv_cache_format="nvfp4", page_size=16)
+    eng = ContinuousEngine(cfg, params, scfg)
+    carry = registry.make_decode_state(cfg, eng.n_slots, scfg.max_len,
+                                       kv_cache_format="nvfp4",
+                                       page_size=16)
+    vec = jnp.zeros((eng.n_slots,), jnp.int32)
+    return eng._decode_impl, (eng.params, vec, carry, vec, vec + 1,
+                              jnp.ones((eng.n_slots,), bool))
+
+
+_PROGRAMS = {"train_step": _train_step_args, "decode": _decode_args}
+
+
+def test_train_step_names_every_fqt_site():
+    import jax
+    from repro.core import fqt
+    f, args = _train_step_args()
+    toks = _scope_tokens(jax.jit(f).lower(*args))
+    want = {scopes.FQT_Q + p for p in fqt.POINTS} | {
+        scopes.DOT_FWD, scopes.DOT_DGRAD, scopes.DOT_WGRAD, scopes.ATTN,
+        scopes.SIGMA_PROBE, scopes.OPTIM, scopes.LAYER_SCAN}
+    assert set(fqt.SITE_SCOPES) == {scopes.FQT_Q + p for p in fqt.POINTS}
+    assert want <= toks, want - toks
+
+
+def test_decode_program_names_dequant_and_kv():
+    import jax
+    f, args = _decode_args()
+    toks = _scope_tokens(jax.jit(f).lower(*args))
+    want = {scopes.PACKED_DEQUANT, scopes.KV_READ, scopes.KV_WRITE,
+            scopes.FQT_Q + "fwd_a", scopes.DOT_FWD, scopes.ATTN,
+            scopes.LAYER_SCAN}
+    assert want <= toks, want - toks
+
+
+@pytest.mark.parametrize("program", sorted(_PROGRAMS))
+def test_scopes_change_only_metadata(program, monkeypatch):
+    """The program's jaxpr is the same with every scope taken out: scopes
+    touch only the name stack, so they cannot move tokens, losses or
+    fusion."""
+    import contextlib
+    import jax
+    f, args = _PROGRAMS[program]()
+    with_scopes = str(jax.make_jaxpr(f)(*args))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    f, args = _PROGRAMS[program]()
+    assert str(jax.make_jaxpr(f)(*args)) == with_scopes
 
 
 # ---- train telemetry: one √3-series entry per logged step ---------------------

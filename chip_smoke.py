@@ -11,7 +11,12 @@
   C  serving: tinyllama-1.1b (22 layers, d_model 2048, 4 KV heads, d_ff
      5632) through ``ContinuousEngine`` as ``launch/serve.py --queue``
      builds it, with packed NVFP4 weights and the paged nvfp4 KV cache;
-     the packed engine's greedy tokens must equal the unpacked engine's
+     on the XLA route (``dequant()`` + dot) the packed engine's greedy
+     tokens must equal the unpacked engine's; on the Pallas route every
+     GEMM must take the kernel and match the XLA route's product to f32
+     accumulation order, and its tokens must lie no further below a
+     teacher-forced reference than the limits ``FAR_LIMITS``, which an
+     MXFP4 control must fail
   D  tinyllama-1.1b under ``--mesh tp=4`` (one KV head per chip); its
      tokens must equal the same requests served on one device
 
@@ -25,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import math
@@ -49,6 +55,23 @@ SERVE_ARGS = ["--arch", "tinyllama-1.1b", "--queue", "16",
 # sign.  Allowed: a quarter of one bf16 ulp of the loss.  (The gap measured
 # on the CPU at llama2-350m.smoke(), 1024 tokens, was 8.4e-5 relative.)
 LOSS_RTOL = 2.0 ** -10
+# Phase C, kernel route.  Its GEMM products equal the XLA route's, but XLA
+# rounds each product to bf16 where its fusions fall (excess precision may
+# skip a rounding), so the engine's programs round apart from the unpacked
+# engine's, and FP4 activation codes carry the last bits on: its tokens
+# are other near-ties.  What is checked is how far below the best they
+# fall.  The reference is the QAF forward of the unpacked weights
+# (``registry.forward``: fake-quant GEMMs, K/V unquantized) over each
+# engine's own stream; a served token is far when it lies more than FAR
+# logits below the reference's best (random-init tinyllama-1.1b logits
+# have a standard deviation of ~1).  The limits lie between the unpacked
+# engine's readings and those of an MXFP4 control (weights and activations
+# one precision below NVFP4), which must fail them.  On the chip, over
+# three request sets: unpacked 0.064-0.078 of tokens and <= 0.19 in the
+# worst request; MXFP4 0.699-0.775 and >= 0.875 (PERF.md Open questions
+# 15).
+FAR = 1.0
+FAR_LIMITS = {"token_far_share": 0.25, "request_far_share_max": 0.4}
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 GB = 1e9
 
@@ -238,7 +261,92 @@ def check_same_tokens(phase: str, eng, reqs, got, want, want_margins):
     raise RuntimeError(f"{phase}: token streams differ for requests {bad}")
 
 
+def check_kernel_gemms(params) -> None:
+    """The Pallas route's product against the XLA route's on one slice of
+    every packed weight, at a decode (8) and a prefill (128) row count:
+    the same bf16 products, summed in f32 in another order."""
+    from repro.core import fqt
+    from repro.core.quantize import NVFP4, fake_quant
+    from repro.kernels import ops
+
+    def first(w):                       # one matrix of a stacked weight
+        return jax.tree.map(lambda a: a[(0,) * (w.packed.ndim - 2)], w)
+
+    leaves = [first(w) for w in jax.tree.leaves(
+        params, is_leaf=lambda x: hasattr(x, "dequant"))
+        if hasattr(w, "dequant")]
+    worst = 0.0
+    for w in {tuple(w.shape): w for w in leaves}.values():
+        for m in (8, 128):
+            x = jax.random.normal(jax.random.PRNGKey(m), (m, w.shape[0]),
+                                  jax.numpy.bfloat16)
+            qx = fake_quant(x, NVFP4, axis=-1)
+            _check(ops.packed_kernel_takes(m, w),
+                   f"C: no kernel for {w.shape} at {m} rows")
+            got = np.asarray(fqt.packed_gemm(qx, w, out_dtype=jax.numpy.
+                                             float32))
+            ref = np.asarray(jax.numpy.matmul(
+                qx, w.dequant(), preferred_element_type=jax.numpy.float32))
+            err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+            worst = max(worst, err)
+            _check(err < 1e-5, f"C: kernel {w.shape} x {m} rows off by "
+                   f"{err:.2e} of the largest output")
+    print(f"[C] kernel GEMMs match the XLA route: worst gap {worst:.2e} of "
+          f"the largest output ({len(leaves)} packed weights)", flush=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_gaps(cfg):
+    """jit (params, tokens) -> how far each next token lies below the best
+    logit of the QAF forward at the row before it."""
+    from repro.core import fqt
+    from repro.models import registry
+
+    @jax.jit
+    def gaps(params, toks):
+        logits = registry.forward(params, cfg, fqt.qaf_config(),
+                                  {"tokens": toks})[0].astype(np.float32)
+        nxt = jax.numpy.roll(toks, -1, axis=1)[..., None]
+        return (logits.max(-1)
+                - jax.numpy.take_along_axis(logits, nxt, -1)[..., 0])
+    return gaps
+
+
+def far_readings(cfg, params, reqs, streams) -> dict:
+    """Teacher-forced readings of the served ``streams`` (rid -> tokens)
+    against the reference: the share of all served tokens lying more than
+    FAR logits below the reference's best, the same share in the worst
+    request, and the mean gap."""
+    prompts = {r.rid: np.asarray(r.prompt) for r in reqs}
+    seqs = [np.concatenate([prompts[rid], streams[rid]]) for rid in streams]
+    toks = np.zeros((len(seqs), max(map(len, seqs))), np.int32)
+    for b, seq in enumerate(seqs):
+        toks[b, :len(seq)] = seq
+    g = np.asarray(_reference_gaps(cfg)(params, toks))
+    per = [g[b, len(prompts[rid]) - 1:len(seqs[b]) - 1]
+           for b, rid in enumerate(streams)]
+    every = np.concatenate(per)
+    return {"token_far_share": float(np.mean(every > FAR)),
+            "request_far_share_max": max(float(np.mean(x > FAR))
+                                         for x in per),
+            "token_gap_mean": float(np.mean(every)), "tokens": every.size}
+
+
+def _within(readings: dict) -> bool:
+    return all(readings[k] <= v for k, v in FAR_LIMITS.items())
+
+
+def _far_report(phase: str, readings: dict) -> None:
+    print(f"[{phase}] against the reference: "
+          + ", ".join(f"{k} {v:.4f}" if isinstance(v, float) else f"{k} {v}"
+                      for k, v in readings.items())
+          + f" (limits {FAR_LIMITS})", flush=True)
+
+
 def serve_phase(clock: Clock) -> None:
+    from repro.core import fqt
+    from repro.core.quantize import MXFP4
+    from repro.kernels import ops
     from repro.serve import ContinuousEngine
 
     cfg, params, scfg, reqs = _serve_setup()
@@ -247,13 +355,56 @@ def serve_phase(clock: Clock) -> None:
     margins = ref.margins
     del ref
     gc.collect()
-    eng = ContinuousEngine(cfg, params, scfg)
-    got = serve_run("C packed", eng, reqs, clock)
+    sound = far_readings(cfg, params, reqs, want)
+    _far_report("C unpacked", sound)
+    _check(_within(sound), "C: the unpacked engine fails the far-share "
+           "limits")
+    # the packed store: on the XLA route the packed engine multiplies the
+    # unpacked engine's operands with the same dots, token for token
+    on_tpu = ops._on_tpu
+    ops._on_tpu = lambda: False
+    try:
+        eng = ContinuousEngine(cfg, params, scfg)
+        got = serve_run("C packed, XLA route", eng, reqs, clock)
+    finally:
+        ops._on_tpu = on_tpu
     check_same_tokens("C", eng, reqs, got, want, margins)
-    warm = serve_run("C packed, warm rerun", eng, reqs, clock)
+    del eng
+    gc.collect()
+    # the Pallas route: every GEMM on the kernel, each product the XLA
+    # route's, and the tokens within the far-share limits (FAR_LIMITS)
+    eng = ContinuousEngine(cfg, params, scfg)
+    got = serve_run("C packed, kernel", eng, reqs, clock)
+    routes = eng.packed_gemms
+    print(f"[C] packed GEMM routes: {routes}", flush=True)
+    _check(routes.get("packed_gemm.kernel", 0) > 0
+           and "packed_gemm.dequant" not in routes,
+           "C: a packed GEMM did not take the kernel")
+    check_kernel_gemms(eng.params)
+    same = sum(int(np.array_equal(got[r], want[r])) for r in want)
+    agree = np.mean([np.mean(np.asarray(got[r][:len(want[r])]) == want[r])
+                     for r in want])
+    print(f"[C] kernel route against unpacked: {same} of {len(want)} "
+          f"streams identical, {agree:.1%} of tokens agree", flush=True)
+    kernel = far_readings(cfg, params, reqs, got)
+    _far_report("C packed, kernel", kernel)
+    _check(_within(kernel), "C: the kernel route's tokens fall further "
+           "below the reference than the limits allow")
+    warm = serve_run("C packed, kernel, warm rerun", eng, reqs, clock)
     _check(eng.prefill_compiles == 1 and eng.decode_compiles == 1,
            "C: the warm rerun compiled again")
-    check_same_tokens("C warm", eng, reqs, warm, want, margins)
+    _check(all(np.array_equal(warm[r], got[r]) for r in got),
+           "C: the warm rerun gave other tokens")
+    del eng
+    gc.collect()
+    # the control: one precision below, the same check must fail
+    eng = ContinuousEngine(cfg, params, scfg,
+                           qcfg=fqt.QuantConfig(fwd_w=MXFP4, fwd_a=MXFP4))
+    low = serve_run("C MXFP4 control", eng, reqs, clock)
+    control = far_readings(cfg, params, reqs, low)
+    _far_report("C MXFP4 control", control)
+    _check(not _within(control), "C: the MXFP4 control passes the far-share "
+           "limits, so they cannot tell a fault")
 
 
 def mesh_phase(clock: Clock) -> None:

@@ -33,6 +33,7 @@ import numpy as np
 from repro.core.quantize import (BlockQuantSpec, NVFP4, MXFP4, fake_quant,
                                  PackedQuantizedTensor)
 from repro.obs import scopes
+from repro.obs.trace import Counters
 
 # the six quantization points
 POINTS = ("fwd_w", "fwd_a", "bwd_w", "bwd_g", "upd_g", "upd_a")
@@ -43,6 +44,10 @@ SITE_SCOPES = tuple(scopes.FQT_Q + p for p in ("fwd_a", "fwd_w", "bwd_g",
 # the paper's selective-rounding scheme (eqs. 4-6): SR on neural gradients in
 # backward+update GEMMs and on activations in the update GEMM.
 PAPER_SR_POINTS: FrozenSet[str] = frozenset({"bwd_g", "upd_g", "upd_a"})
+# trace-time counts of packed-weight GEMMs and their packed bytes by route:
+# ``packed_gemm.kernel[.bytes]`` and ``packed_gemm.dequant[.bytes]``
+PACKED_GEMM = "packed_gemm."
+PACKED_GEMMS = Counters()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +60,8 @@ class QuantConfig:
     bwd_g: Optional[BlockQuantSpec] = None
     upd_g: Optional[BlockQuantSpec] = None
     upd_a: Optional[BlockQuantSpec] = None
-    # "jnp" (fake-quant reference path) or "pallas" (fused TPU kernels)
+    # "jnp" (fake-quant reference path) or "pallas" (fused TPU kernels) for
+    # the training GEMMs; packed weights route by platform and shape
     impl: str = "jnp"
 
     @property
@@ -257,31 +263,50 @@ _fp4_matmul.defvjp(_fwd_rule, _bwd_rule)
 # ---- pre-quantized (packed) weights: the quantize-once serving path ----------
 
 
+def packed_gemm(x: jax.Array, w: PackedQuantizedTensor,
+                out_dtype=None) -> jax.Array:
+    """(..., K) @ packed (K, N) -> (..., N), f32-accumulated, ``x`` as given.
+
+    On TPU, for the shapes it takes, one Pallas kernel dequantizes the
+    weight tile by tile in VMEM (``kernels/fp4_matmul.packed_block_matmul``);
+    elsewhere (CPU, odd shapes) ``w.dequant()`` and ``jnp.matmul``.  Each
+    call counts its route and the weight's packed bytes in ``PACKED_GEMMS``
+    at trace time (a layer scan's body counts once).
+    """
+    from repro.kernels import ops as kops
+    K, N = w.shape
+    out_dtype = x.dtype if out_dtype is None else out_dtype
+    lead = x.shape[:-1]
+    m = int(np.prod(lead))
+    route = "kernel" if kops.packed_kernel_takes(m, w) else "dequant"
+    PACKED_GEMMS.inc(PACKED_GEMM + route)
+    PACKED_GEMMS.inc(PACKED_GEMM + route + ".bytes", w.nbytes())
+    if route == "kernel":
+        # f32 out of the kernel and the cast in XLA, as on the dequant
+        # route, so that XLA places (or, with excess precision, skips) the
+        # bf16 rounding alike: a model forward then agrees bit for bit
+        y = kops.packed_block_matmul(x.reshape(m, K), w,
+                                     out_dtype=jnp.float32)
+        y = y.reshape(lead + (N,))
+    else:
+        y = jnp.matmul(x, w.dequant(), preferred_element_type=jnp.float32)
+    return y.astype(out_dtype)
+
+
 def _packed_forward(x: jax.Array, w: PackedQuantizedTensor, seed: jax.Array,
                     cfg: QuantConfig) -> jax.Array:
     """[Forward] z = Q_rtn(a) @ dequant(w_packed): the weight was quantized
     ONCE (Engine init / checkpoint export) so only the activation is
-    quantized per GEMM.  Bit-identical to ``_forward`` with ``fwd_w`` set —
-    ``PackedQuantizedTensor.dequant`` reconstructs exactly the fake-quant
-    grid values.  Inference-only (no custom_vjp; serving never backprops).
+    quantized per GEMM.  ``PackedQuantizedTensor.dequant`` reconstructs
+    exactly the fake-quant grid values, so the dequant route is
+    bit-identical to ``_forward`` with ``fwd_w`` set; the kernel route's
+    product differs only in the order of the f32 accumulation.
+    Inference-only (no custom_vjp; serving never backprops).
     """
-    K, N = w.shape
-    fwd_a = _if_divisible(cfg.fwd_a, K)
-    if (cfg.impl == "pallas" and fwd_a is not None and w.axis == -2
-            and fwd_a.block == w.block):
-        from repro.kernels import ops as kops
-        rb = (_site_bits(x.shape, seed, 0).reshape(-1, K)
-              if fwd_a.stochastic else None)
-        x2 = x.reshape(-1, K)
-        with jax.named_scope(scopes.DOT_FWD):
-            y = kops.packed_block_matmul(x2, w, fwd_a, a_rbits=rb,
-                                         out_dtype=x.dtype)
-        return y.reshape(x.shape[:-1] + (N,))
-    qx = _maybe_q(x, fwd_a, axis=-1, seed=seed, site=0)
-    wd = w.dequant()
+    K = w.shape[0]
+    qx = _maybe_q(x, _if_divisible(cfg.fwd_a, K), axis=-1, seed=seed, site=0)
     with jax.named_scope(scopes.DOT_FWD):
-        y = jnp.matmul(qx, wd, preferred_element_type=jnp.float32)
-    return y.astype(x.dtype)
+        return packed_gemm(qx, w)
 
 
 def fp4_matmul(x: jax.Array, w: jax.Array, *, cfg: QuantConfig,

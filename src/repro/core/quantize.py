@@ -193,19 +193,30 @@ def scale_health(x: jax.Array, spec: BlockQuantSpec, *,
 _E2M1_GRID = np.array([0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0], np.float32)
 
 
-def pack_e2m1(codes: jax.Array) -> jax.Array:
-    """Pack E2M1 grid values into nibbles, two per uint8 (last axis even).
-
-    Arithmetic encode (no float4 dtype — jax<0.5 cannot hold float4 arrays):
-    nibble = sign<<3 | grid_index, the float4_e2m1fn bit layout.  ``codes``
-    must hold exact grid values (the quantizers' output), any float dtype.
-    """
-    if codes.shape[-1] % 2:
-        raise ValueError(f"last axis must be even to pack, got {codes.shape}")
+def _e2m1_nibbles(codes: jax.Array) -> jax.Array:
+    """Exact E2M1 grid values -> 4-bit codes (uint8), sign<<3 | grid index:
+    the float4_e2m1fn bit layout, by arithmetic (jax<0.5 cannot hold
+    float4 arrays)."""
     absv = jnp.abs(codes).astype(jnp.float32)
     idx = jnp.searchsorted(jnp.asarray(_E2M1_GRID), absv).astype(jnp.uint8)
     sign = (codes.astype(jnp.float32) < 0).astype(jnp.uint8)
-    nib = (sign << 3) | idx
+    return (sign << 3) | idx
+
+
+def _e2m1_values(nib: jax.Array) -> jax.Array:
+    """4-bit codes (any integer dtype, high bits clear) -> f32 grid values."""
+    mag = jnp.asarray(_E2M1_GRID)[nib & 0x7]
+    return mag * jnp.where(nib & 0x8, -1.0, 1.0)
+
+
+def pack_e2m1(codes: jax.Array) -> jax.Array:
+    """Pack E2M1 grid values into nibbles, two per uint8 (last axis even):
+    element 2j in the low nibble, 2j+1 in the high one — the KV cache's
+    row format.  ``codes`` must hold exact grid values (the quantizers'
+    output), any float dtype."""
+    if codes.shape[-1] % 2:
+        raise ValueError(f"last axis must be even to pack, got {codes.shape}")
+    nib = _e2m1_nibbles(codes)
     lo, hi = nib[..., 0::2], nib[..., 1::2]
     return lo | (hi << 4)
 
@@ -222,12 +233,40 @@ def unpack_e2m1(packed: jax.Array, dtype=jnp.float32) -> jax.Array:
     return flat.astype(dtype)
 
 
+def _pack_blocked(codes: jax.Array, axis: int, block: int) -> jax.Array:
+    """Pack E2M1 grid values two per uint8 along the blocking ``axis``:
+    byte r of block b holds element r (low nibble) and element
+    r + block/2 (high nibble) of that block, so both nibbles of a byte
+    share the block's scale.  The packed axis is halved."""
+    ax = _norm_axis(codes.ndim, axis)
+    nib = _blocked(_e2m1_nibbles(codes), ax, block)      # (..., nb, B, ...)
+    half = block // 2
+    lo = jax.lax.slice_in_dim(nib, 0, half, axis=ax + 1)
+    hi = jax.lax.slice_in_dim(nib, half, block, axis=ax + 1)
+    packed = lo | (hi << 4)                              # (..., nb, B/2, ...)
+    shape = list(codes.shape)
+    shape[ax] //= 2
+    return packed.reshape(shape)
+
+
+def _unpack_blocked(packed: jax.Array, axis: int, block: int) -> jax.Array:
+    """Inverse of ``_pack_blocked`` in block form: f32 grid values of shape
+    (..., nblocks, block, ...) along the blocking ``axis``."""
+    ax = _norm_axis(packed.ndim, axis)
+    p = _blocked(packed, ax, block // 2)                 # (..., nb, B/2, ...)
+    return jnp.concatenate([_e2m1_values(p & 0xF), _e2m1_values(p >> 4)],
+                           axis=ax + 1)
+
+
 @dataclasses.dataclass(frozen=True)
 class PackedQuantizedTensor:
     """Quantize-once packed NVFP4 storage: 4-bit codes + block scales.
 
     The serving-side counterpart of ``QuantizedTensor``: E2M1 codes are
-    nibble-packed two-per-uint8 along the LAST axis (``packed``), the block
+    nibble-packed two-per-uint8 along the BLOCKING axis (``packed``): byte
+    r of block b holds element r (low nibble) and element r + block/2
+    (high nibble), so both nibbles share block b's scale and a (K, N)
+    weight blocked on K packs to (K/2, N).  The block
     scales live in ``scales`` (float8_e4m3fn when the scale format is E4M3,
     else the source dtype) and ``tscale`` is the per-tensor pow2 scale —
     one per leading *batch* slice when the weight is a stacked layer/expert
@@ -242,7 +281,7 @@ class PackedQuantizedTensor:
     stays valid when leading batch dims are sliced away by scan/vmap).
     """
 
-    packed: jax.Array          # uint8, shape = logical[:-1] + (last/2,)
+    packed: jax.Array          # uint8, logical shape with axis halved
     scales: jax.Array          # logical shape with axis divided by block
     tscale: jax.Array          # f32, shape = leading batch dims (or scalar)
     axis: int                  # negative blocking-axis index
@@ -255,7 +294,9 @@ class PackedQuantizedTensor:
 
     @property
     def shape(self):
-        return self.packed.shape[:-1] + (self.packed.shape[-1] * 2,)
+        ax = self.axis % self.packed.ndim
+        s = self.packed.shape
+        return s[:ax] + (s[ax] * 2,) + s[ax + 1:]
 
     @property
     def ndim(self) -> int:
@@ -289,13 +330,13 @@ class PackedQuantizedTensor:
         """codes * block_scales * tscale, bit-identical to the fake-quant
         (QuantizedTensor) reconstruction of the same tensor."""
         dt = self.dtype
+        ax = self.axis % self.ndim
         with jax.named_scope(scopes.PACKED_DEQUANT):
-            codes = unpack_e2m1(self.packed, dtype=dt)
-            s = jnp.repeat(self.scales.astype(dt), self.block,
-                           axis=self.axis)
+            codes = _unpack_blocked(self.packed, ax, self.block).astype(dt)
+            s = jnp.expand_dims(self.scales.astype(dt), ax + 1)
             t = self.tscale.reshape(
                 self.tscale.shape + (1,) * (codes.ndim - self.tscale.ndim))
-            return (codes * s * t).astype(dt)
+            return (codes * s * t).astype(dt).reshape(self.shape)
 
 
 jax.tree_util.register_dataclass(
@@ -318,7 +359,7 @@ def pack_quantized(qt: QuantizedTensor,
     if spec.data_fmt != "e2m1":
         raise ValueError("packed storage is E2M1-only")
     return PackedQuantizedTensor(
-        packed=pack_e2m1(qt.codes),
+        packed=_pack_blocked(qt.codes, qt.axis, qt.block),
         scales=_pack_scales(qt.scales, spec),
         tscale=jnp.asarray(qt.tscale, jnp.float32),
         axis=qt.axis - qt.codes.ndim,
@@ -433,7 +474,7 @@ def pack_quantize(x: jax.Array, spec: BlockQuantSpec = NVFP4, *,
     codes = formats.quantize(xb / denom, spec.data)
     codes = codes.reshape(x.shape).astype(orig_dtype)
     return PackedQuantizedTensor(
-        packed=pack_e2m1(codes),
+        packed=_pack_blocked(codes, ax, spec.block),
         scales=_pack_scales(scales.astype(orig_dtype), spec),
         tscale=tscale.astype(jnp.float32),
         axis=ax - nd,

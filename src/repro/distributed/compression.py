@@ -150,9 +150,10 @@ def allgather_packed(pt, axis: str, dim: int = 0):
 
     Only the wire format travels: the uint8 nibble codes directly, and the
     block scales bitcast to uint8 for the hop (f8 collectives are not
-    portable across backends; the bytes are identical either way).  ``dim``
-    must not be the nibble-packed last axis (shard FSDP on the contraction
-    axis, as the sharding rules do).
+    portable across backends; the bytes are identical either way).  Any
+    ``dim`` gathers: on the blocking (nibble-packed) axis each shard holds
+    whole blocks, as ``packed_leaf_specs`` guarantees, so the shards'
+    bytes concatenate in block order.
     """
     import dataclasses as _dc
 
@@ -160,8 +161,6 @@ def allgather_packed(pt, axis: str, dim: int = 0):
     from repro.core.quantize import PackedQuantizedTensor
     assert isinstance(pt, PackedQuantizedTensor), type(pt)
     dim = dim % pt.ndim
-    if dim == pt.ndim - 1:
-        raise ValueError("cannot gather along the nibble-packed last axis")
     packed = jax.lax.all_gather(pt.packed, axis, axis=dim, tiled=True)
     if _jnp.dtype(pt.scales.dtype).itemsize == 1:
         sc_u8 = jax.lax.bitcast_convert_type(pt.scales, _jnp.uint8)

@@ -132,7 +132,7 @@ def _divisible(spec: P, shape, mesh: Mesh, path: str = "",
 
     When ``path`` names the leaf (parameter/cache shardings do), every
     dropped axis is DIAGNOSED — logged, or raised with ``strict=True`` —
-    instead of silently replicating: under nibble packing the trailing
+    instead of silently replicating: under nibble packing the blocking
     axis is halved, and a "sharded" deploy quietly holding full replicas
     is a correctness-adjacent perf bug.  Anonymous calls (activation
     constraints, where odd smoke-config dims are routine) stay silent.
@@ -338,29 +338,35 @@ def spec_for_packed(path: str, leaf: PackedQuantizedTensor,
     return {k: P(*v) for k, v in out.items()}
 
 
-def place_serve_params(params, mesh: Mesh):
-    """device_put a (possibly packed) parameter pytree under ``mesh``.
-
-    Packed leaves get ``spec_for_packed`` shardings on their nibble-code /
-    block-scale / tensor-scale arrays; plain leaves follow ``param_spec``.
-    On a 1-device mesh this is the identity placement.
-    """
+def serve_params_shardings(params, mesh: Mesh):
+    """The shardings ``place_serve_params`` puts a (possibly packed)
+    parameter pytree under: packed leaves get ``spec_for_packed`` shardings
+    on their nibble-code / block-scale / tensor-scale arrays; plain leaves
+    follow ``param_spec``.  Leaves that are not arrays stay as they are."""
 
     def one(path, leaf):
         p = _path_str(path)
         if isinstance(leaf, PackedQuantizedTensor):
             sh = spec_for_packed(p, leaf, mesh)
             return leaf.map_leaves(
-                lambda name, x: jax.device_put(
-                    x, NamedSharding(mesh, sh[name])))
+                lambda name, x: NamedSharding(mesh, sh[name]))
         if not hasattr(leaf, "ndim"):
             return leaf
         spec = _divisible(param_spec(p, leaf.ndim, mesh), leaf.shape, mesh,
                           path=p)
-        return jax.device_put(leaf, NamedSharding(mesh, spec))
+        return NamedSharding(mesh, spec)
 
     return jax.tree_util.tree_map_with_path(
         one, params, is_leaf=lambda x: isinstance(x, PackedQuantizedTensor))
+
+
+def place_serve_params(params, mesh: Mesh):
+    """device_put a (possibly packed) parameter pytree under ``mesh``, with
+    ``serve_params_shardings``.  On a 1-device mesh this is the identity
+    placement."""
+    return jax.tree.map(
+        lambda x, s: jax.device_put(x, s) if hasattr(x, "ndim") else x,
+        params, serve_params_shardings(params, mesh))
 
 
 def serve_cache_shardings(cache_struct, mesh: Mesh):

@@ -8,8 +8,8 @@ jax, devices or XLA (``tools/check_env.py --mesh`` runs them standalone).
 The core problem it solves: a ``PackedQuantizedTensor`` stores one logical
 weight as THREE arrays whose shapes disagree with the logical shape —
 
-  * ``packed``  : uint8 nibble codes, logical shape with the LAST axis
-                  halved (two E2M1 values per byte);
+  * ``packed``  : uint8 nibble codes, logical shape with the BLOCKING
+                  axis halved (two E2M1 values of one block per byte);
   * ``scales``  : f8 block scales, logical shape with the BLOCKING axis
                   divided by ``block``;
   * ``tscale``  : f32 per-batch-slice tensor scales (leading dims only).
@@ -135,7 +135,7 @@ def packed_leaf_specs(base_spec: Sequence[Axis], logical_shape: Sequence[int],
     ``d`` of EVERY leaf that carries dim ``d``, or on none of them:
 
       * codes shard dim d only if it also divides the nibble-packed size
-        (d == last: ``logical[-1] // 2``);
+        (d == axis: ``logical[axis] // 2``);
       * scales shard dim d only if it also divides the blocked size
         (d == axis: ``logical[axis] // block``);
       * tscale carries only the leading batch dims (``tscale_ndim``).
@@ -147,7 +147,8 @@ def packed_leaf_specs(base_spec: Sequence[Axis], logical_shape: Sequence[int],
     axis = axis % nd
     base = tuple(base_spec) + (None,) * (nd - len(base_spec))
 
-    packed_shape = tuple(logical_shape[:-1]) + (logical_shape[-1] // 2,)
+    packed_shape = tuple(s // 2 if d == axis else s
+                         for d, s in enumerate(logical_shape))
     scales_shape = tuple(s // block if d == axis else s
                          for d, s in enumerate(logical_shape))
 

@@ -15,6 +15,9 @@ TPU-native adaptation of Blackwell's block-scaled FP4 MMA (DESIGN.md §3):
     (operands pre-transposed so blocks always lie along the contraction
     axis: A (M,K) blocked along K/axis-1, B (K,N) blocked along K/axis-0).
 
+  * ``packed_block_matmul``: the served GEMM, activation x packed NVFP4
+    weight, decoding the weight's 4-bit codes tile by tile in VMEM.
+
 Tile defaults (TM,TN,TK)=(128,128,512): MXU-aligned (128 lanes), VMEM use
 ~1.2 MB for the fused kernel at fp32 — comfortably within the ~16 MB/core
 budget while leaving room for double buffering.
@@ -27,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import BlockQuantSpec
 from repro.kernels import common as c
@@ -204,99 +208,164 @@ def fused_quant_matmul(a: jax.Array, b: jax.Array,
     return out.astype(out_dtype)
 
 
-# ---- packed weights: quantize-a on the fly x unpack-dequant-b ----------------
+# ---- packed weights: activation x packed NVFP4 weight ----------------------
+#
+# The served GEMM.  The weight stays in HBM as its packed store, (K/2, N)
+# uint8 codes paired along K (byte r of block b holds rows r and
+# r + block/2 of that block, ``quantize._pack_blocked``) and (K/block, N)
+# block scales, and is dequantized tile by tile in VMEM: no bf16 copy of
+# it is ever written.  The activation comes in already quantized (RtN at
+# ``fqt.q.fwd_a``) and is split by the wrapper into the columns that meet
+# the low nibbles and those that meet the high ones, so each code plane
+# multiplies its own half of A and no lane is interleaved in the kernel.
+
+# Largest tiles: TM rows of A (the 64-slot decode and the 256-token prefill
+# are one row tile), TN and TK of the weight (the fastest on v5e at the
+# serve cell's shapes, PERF.md §5).
+TM_MAX, TN_MAX, TK_MAX = 256, 2048, 4096
+SCALE_ROWS = 32               # sublanes of an 8-bit tile: a chunk's scale rows
 
 
-def _packed_kernel(a_ref, bp_ref, bs_ref, arb_ref, tsa_ref, tsb_ref, o_ref, *,
-                   block: int, block_b: int, qa: dict, mxu_dtype):
-    """A tile is quantized in VREGs exactly as in ``_fused_kernel``; the B
-    tile arrives as nibble-packed E2M1 codes (half the bytes of an int8
-    operand, 1/4 of bf16) + block scales, and is unpacked/dequantized
-    in VREGs — the decode-path weight stream out of HBM is ~4x smaller."""
+def packed_tiles(M: int, K: int, N: int, block: int) -> Optional[tuple]:
+    """(TM, TN, TK, CK) for an (M, K) x packed (K, N) GEMM, or None where
+    the kernel does not take the shape.  TM is M up to TM_MAX; above it,
+    the fewest row tiles, each a multiple of 16 rows (every row tile
+    decodes the weight again, so M is padded by less than 16 a tile
+    rather than up to a multiple of TM_MAX).  TN is a multiple of 128
+    dividing N; TK divides K and is a whole number of chunks of CK code
+    rows, a chunk being the rows whose scales fill one 8-bit tile (32
+    blocks), or TK = K (one chunk) where K has no such chunk."""
+    if N % c.LANE or K % block or block % 16:
+        return None
+    n_m = -(-M // TM_MAX)
+    TM = M if n_m == 1 else -(-M // (16 * n_m)) * 16
+    TN = c.pick_tile(N, TN_MAX, c.LANE)
+    span = SCALE_ROWS * block                    # K rows of one chunk
+    if K % span == 0:
+        return TM, TN, c.pick_tile(K, max(TK_MAX, span), span), span // 2
+    if K % (2 * c.LANE) or K > TK_MAX:
+        return None
+    return TM, TN, K, K // 2
+
+
+def _nibble_k(p: jax.Array, shift: int) -> jax.Array:
+    """f32 E2M1 grid values of the nibble at bit ``shift`` (0 or 4) of the
+    int32 bytes ``p``: the magnitude bits eem go under an f32 exponent
+    bias of 126, which is exact for e > 0 and gives 0.5 + m/4 for e = 0;
+    min(x, 2x - 1) maps that to m/2 and leaves x >= 1 as it is.  The sign
+    bit is or-ed in last."""
+    bits = ((p << (22 - shift)) & (7 << 22)) + (126 << 23)
+    mag = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    mag = jnp.minimum(mag, mag + mag - 1.0)
+    sign = (p << (28 - shift)) & jnp.int32(-2 ** 31)
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(mag, jnp.int32) | sign, jnp.float32)
+
+
+def _packed_kernel(alo_ref, ahi_ref, w_ref, s_ref, t_ref, o_ref, acc_ref, *,
+                   half: int, ck: int, n_chunks: int):
+    """acc += A_lo @ W_lo + A_hi @ W_hi over one (TK/2, TN) code tile, in
+    chunks of ``ck`` code rows: each chunk's bytes are decoded on the VPU
+    into both nibble planes, each plane's rows scaled by their block's
+    scale (one scale row per ``half`` code rows), and multiplied on the
+    MXU with f32 accumulation.  The tensor scale is applied once, at the
+    last K step."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tsa = tsa_ref[0, 0]
-    tsb = tsb_ref[0, 0]
-    a = a_ref[...].astype(jnp.float32)            # (TM, TK) blocked along TK
-    ad = _quant_tile(a, arb_ref[...], tsa, axis=1, block=block, **qa)
-    bcodes = c.unpack_e2m1_k(bp_ref[...])         # (TK, TN) f32 grid values
-    bsc = bs_ref[...].astype(jnp.float32)         # (TK//block_b, TN)
-    bd = bcodes * c.block_repeat_k(bsc, block_b, 0)
-    o_ref[...] += jnp.dot(ad.astype(mxu_dtype), bd.astype(mxu_dtype),
-                          preferred_element_type=jnp.float32) * (tsa * tsb)
+    tn = w_ref.shape[-1]
+    nb = ck // half
+    dt = alo_ref.dtype
+
+    def chunk(i, carry):
+        r = pl.multiple_of(i * ck, ck)
+        p = w_ref[pl.ds(r, ck), :].astype(jnp.int32)
+        s = s_ref[pl.ds(pl.multiple_of(i * nb, nb), nb), :].astype(
+            jnp.float32)
+        s = jnp.broadcast_to(s[:, None, :], (nb, half, tn)).reshape(ck, tn)
+        acc_ref[...] += (
+            jnp.dot(alo_ref[:, pl.ds(r, ck)], (_nibble_k(p, 0) * s).astype(dt),
+                    preferred_element_type=jnp.float32)
+            + jnp.dot(ahi_ref[:, pl.ds(r, ck)],
+                      (_nibble_k(p, 4) * s).astype(dt),
+                      preferred_element_type=jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _done():
+        o_ref[...] = (acc_ref[...] * t_ref[0, 0]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "spec_a", "block_b", "interpret", "tm", "tn", "tk", "out_dtype"))
+@functools.partial(jax.jit, static_argnames=("block", "out_dtype",
+                                             "interpret"))
 def packed_block_matmul(a: jax.Array, b_packed: jax.Array,
-                        b_scales: jax.Array, b_tscale: jax.Array,
-                        spec_a: BlockQuantSpec, *, block_b: int = 16,
-                        a_rbits: Optional[jax.Array] = None,
-                        out_dtype=jnp.float32, interpret: bool = False,
-                        tm: int = 128, tn: int = 256,
-                        tk: int = 512) -> jax.Array:
-    """Quantize-a x packed-b GEMM: the quantize-once serving hot path.
+                        b_scales: jax.Array, b_tscale: jax.Array, *,
+                        block: int = 16, out_dtype=None,
+                        interpret: bool = False) -> jax.Array:
+    """(M, K) activation x packed (K, N) NVFP4 weight -> (M, N).
 
-    ``b_packed``: (K, N//2) uint8 nibble pairs (pack_e2m1 layout, packed
-    along N); ``b_scales``: (K//block_b, N) block scales (float8/bf16/f32);
-    ``b_tscale``: scalar pow2 tensor scale.  A is quantized on the fly with
-    ``spec_a`` (blocks along K), matching ``fused_quant_matmul``'s A side.
-
-    Default TN=256 keeps the packed tile's last dim at 128 lanes on TPU;
-    on the CPU backend the kernel runs in interpret mode like the others.
+    ``b_packed``: (K/2, N) uint8, ``b_scales``: (K/block, N) block scales
+    (float8 or the source dtype), ``b_tscale``: the weight's scalar pow2
+    tensor scale.  ``a`` is multiplied as given (quantize it first); its
+    dtype is the MXU's.  Accumulates in f32; only the order of that
+    accumulation differs from ``a @ dequant(b)``.
     """
     M, K = a.shape
-    K2, halfN = b_packed.shape
-    N = halfN * 2
-    assert K == K2, (a.shape, b_packed.shape)
-    B = spec_a.block
-    if K % B or K % block_b:
-        raise ValueError(f"K={K} not divisible by blocks {B}/{block_b}")
-
-    from repro.kernels.ref import tensor_scale_ref
-    tsa = tensor_scale_ref(a, spec_a).reshape(1, 1)
-    tsb = jnp.asarray(b_tscale, jnp.float32).reshape(1, 1)
-
-    dummy = jnp.zeros((1, 1), jnp.uint32)
-    if not spec_a.stochastic:
-        a_rbits = dummy
-    elif a_rbits is None or a_rbits.shape != a.shape:
-        raise ValueError("spec_a stochastic requires a_rbits of a.shape")
-
-    TM = c.pick_tile(M, tm, c.SUBLANE)
-    TN = c.pick_tile(N, tn, 2 * c.LANE)          # packed tile: TN/2 lanes
-    # the (TK/block_b, TN) scale tile's rows: float8 tiles hold 32 sublanes
-    TK = c.pick_tile(K, tk, max(c.LANE, 32 * block_b))
-    grid = (M // TM, N // TN, K // TK)
-
-    kernel = functools.partial(
-        _packed_kernel, block=B, block_b=block_b, qa=_quant_params(spec_a),
-        mxu_dtype=_mxu_dtype(spec_a))
-
-    rb_spec = (pl.BlockSpec((TM, TK), lambda i, j, k: (i, k))
-               if spec_a.stochastic
-               else pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)))
-
+    N = b_packed.shape[1]
+    if b_packed.shape[0] * 2 != K or b_scales.shape != (K // block, N):
+        raise ValueError(f"packed weight {b_packed.shape}/{b_scales.shape} "
+                         f"does not match K={K}, block={block}")
+    tiles = packed_tiles(M, K, N, block)
+    if tiles is None:
+        raise ValueError(f"no packed GEMM tiling for ({M}, {K}) x ({K}, {N})")
+    TM, TN, TK, CK = tiles
+    out_dtype = a.dtype if out_dtype is None else out_dtype
+    half = block // 2
+    # the columns of A that meet each nibble plane, in code-row order
+    a4 = a.reshape(M, K // block, 2, half)
+    a_lo = a4[:, :, 0, :].reshape(M, K // 2)
+    a_hi = a4[:, :, 1, :].reshape(M, K // 2)
+    Mp = -(-M // TM) * TM
+    if Mp != M:
+        a_lo = jnp.pad(a_lo, ((0, Mp - M), (0, 0)))
+        a_hi = jnp.pad(a_hi, ((0, Mp - M), (0, 0)))
+    ts = jnp.asarray(b_tscale, jnp.float32).reshape(1, 1)
+    isz = jnp.dtype(a.dtype).itemsize
+    osz = jnp.dtype(out_dtype).itemsize
+    ssz = b_scales.dtype.itemsize
+    # double-buffered inputs, the accumulator and output, and a chunk's
+    # decode temporaries (~24 bytes a weight: int32 codes, f32 planes)
+    vmem = (2 * (TM * TK * isz + TK // 2 * TN + TK // block * TN * ssz)
+            + TM * TN * (4 + 2 * osz) + 24 * CK * TN)
+    a_spec = pl.BlockSpec((TM, TK // 2), lambda j, i, k: (i, k))
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_packed_kernel, half=half, ck=CK,
+                          n_chunks=TK // 2 // CK),
+        grid=(N // TN, Mp // TM, K // TK),
         in_specs=[
-            pl.BlockSpec((TM, TK), lambda i, j, k: (i, k)),
-            pl.BlockSpec((TK, TN // 2), lambda i, j, k: (k, j)),
-            pl.BlockSpec((TK // block_b, TN), lambda i, j, k: (k, j)),
-            rb_spec,
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            a_spec, a_spec,
+            pl.BlockSpec((TK // 2, TN), lambda j, i, k: (k, j)),
+            pl.BlockSpec((TK // block, TN), lambda j, i, k: (k, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((TM, TN), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        out_specs=pl.BlockSpec((TM, TN), lambda j, i, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+        scratch_shapes=[pltpu.VMEM((TM, TN), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 100 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * Mp * K * N, transcendentals=0,
+            bytes_accessed=(N // TN) * Mp * K * isz + K // 2 * N
+            + K // block * N * ssz + Mp * N * osz),
         interpret=interpret,
-    )(a, b_packed, b_scales, a_rbits, tsa, tsb)
-    return out.astype(out_dtype)
+    )(a_lo, a_hi, b_packed, b_scales, ts)
+    return out[:M] if Mp != M else out
 
 
 # ---- tensor-parallel form (mesh-native serving) -------------------------------
@@ -340,6 +409,7 @@ def tp_fp4_matmul(x, w, *, cfg, mesh, seed=None, parallel: str = "column",
 
     from repro.core import fqt
     from repro.core.quantize import PackedQuantizedTensor
+    from repro.kernels import ops as kops
 
     if not isinstance(w, PackedQuantizedTensor) or w.ndim != 2:
         raise ValueError("tp_fp4_matmul needs a 2D PackedQuantizedTensor")
@@ -375,8 +445,8 @@ def tp_fp4_matmul(x, w, *, cfg, mesh, seed=None, parallel: str = "column",
         if gather_axis:
             from repro.distributed.compression import allgather_packed
             w_l = allgather_packed(w_l, gather_axis, dim=0)
-        y = jnp.matmul(qx_l, w_l.dequant(),
-                       preferred_element_type=jnp.float32)
+        with kops.program_devices(1):       # each shard runs on one
+            y = fqt.packed_gemm(qx_l, w_l, out_dtype=jnp.float32)
         if parallel == "row":
             y = jax.lax.psum(y, tp)
         return y.astype(x.dtype)

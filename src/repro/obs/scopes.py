@@ -11,9 +11,11 @@ device op, which is how a trace's device time is put down to a site:
     of ``core/fqt.POINTS``;
   * ``fqt.dot.fwd`` / ``fqt.dot.dgrad`` / ``fqt.dot.wgrad`` — the three
     GEMMs of an FQT matmul (with the Pallas path, the fused
-    quantize-and-multiply call);
-  * ``packed.dequant`` — a packed NVFP4 weight's unpack, scale repeat and
-    multiply (``PackedQuantizedTensor.dequant``);
+    quantize-and-multiply call); on TPU a packed weight's GEMM is one
+    Pallas call here that dequantizes its tiles in VMEM;
+  * ``packed.dequant`` — the fallback route of a packed NVFP4 weight (CPU,
+    shapes the kernel does not take): unpack, scale and multiply to a
+    bf16 weight (``PackedQuantizedTensor.dequant``);
   * ``kv.read`` / ``kv.write`` — the paged KV cache's page gather and row
     dequant, and its row quantize and scatter;
   * ``attn.core`` — attention itself (scores, softmax, the value product),

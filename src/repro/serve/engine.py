@@ -53,6 +53,7 @@ Two engines share the packed-weight/packed-cache machinery:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -63,11 +64,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import fqt
 from repro.distributed import sharding as shd
+from repro.kernels import ops as kops
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.models.layers import TRASH_PAGE, PagedKVCache
 from repro.obs.scopes import ENGINE_TRACK, PHASES, TICK
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, Counters
 from repro.serve import packing
 from repro.serve.metrics import MetricsRecorder
 from repro.serve.scheduler import Request, Scheduler
@@ -160,6 +162,24 @@ def _greedy_margin(logits: jax.Array) -> jax.Array:
     return top2[..., 0] - top2[..., 1]
 
 
+def _jit_on_mesh(engine, fn, **kw):
+    """``jax.jit(fn, **kw)`` traced for ``engine.mesh``: a packed GEMM takes
+    the Pallas kernel only in a program over one device, since GSPMD cannot
+    partition a Mosaic call (``kernels/ops.program_devices``).  The packed
+    GEMM sites each trace adds to ``fqt.PACKED_GEMMS`` are also counted in
+    ``engine._gemms``, so each engine reads its own."""
+    @functools.wraps(fn)
+    def traced(*args):
+        before = fqt.PACKED_GEMMS.snapshot()
+        with kops.program_devices(engine.mesh.devices.size):
+            out = fn(*args)
+        for k, v in fqt.PACKED_GEMMS.snapshot().items():
+            if v != before.get(k, 0):
+                engine._gemms.inc(k, v - before.get(k, 0))
+        return out
+    return jax.jit(traced, **kw)
+
+
 class Engine:
     """Single-model LOCKSTEP serving engine over the uniform registry API."""
 
@@ -184,12 +204,14 @@ class Engine:
             if (pack_weights and self.qcfg.fwd_w is not None) else None
         self.params = packing.pack_model_params(cfg, params, spec,
                                                 mesh=self.mesh)
+        self._gemms = Counters()
 
         # the weights are an ARGUMENT of every compiled program, never a
         # closed-over constant: a constant is copied into the executable
         # (GBs at published widths — host RAM and cache entries blow up)
-        self._prefill = jax.jit(self._prefill_impl)
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(3,))
+        self._prefill = _jit_on_mesh(self, self._prefill_impl)
+        self._decode = _jit_on_mesh(self, self._decode_impl,
+                                    donate_argnums=(3,))
 
     def _replicate(self, *xs):
         """Pin small host-facing arrays (tokens/masks/keys) replicated on
@@ -314,6 +336,7 @@ class ContinuousEngine:
             if (pack_weights and self.qcfg.fwd_w is not None) else None
         self.params = packing.pack_model_params(cfg, params, spec,
                                                 mesh=self.mesh)
+        self._gemms = Counters()
 
         self.n_slots = scfg.max_slots or scfg.batch_size
         psz = scfg.page_size
@@ -367,13 +390,16 @@ class ContinuousEngine:
         self._root = jax.random.PRNGKey(scfg.seed)
 
         # weights are the first argument of every program (see Engine)
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(5,))
-        self._prefill_sfx = jax.jit(self._prefill_suffix_impl,
-                                    donate_argnums=(6,))
-        self._prefill_chk = jax.jit(self._prefill_chunk_impl,
-                                    donate_argnums=(4,))
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(2,))
-        self._verify = jax.jit(self._verify_k_impl, donate_argnums=(2,))
+        self._prefill = _jit_on_mesh(self, self._prefill_impl,
+                                     donate_argnums=(5,))
+        self._prefill_sfx = _jit_on_mesh(self, self._prefill_suffix_impl,
+                                         donate_argnums=(6,))
+        self._prefill_chk = _jit_on_mesh(self, self._prefill_chunk_impl,
+                                         donate_argnums=(4,))
+        self._decode = _jit_on_mesh(self, self._decode_impl,
+                                    donate_argnums=(2,))
+        self._verify = _jit_on_mesh(self, self._verify_k_impl,
+                                    donate_argnums=(2,))
 
     def _replicate(self, *xs):
         """See ``Engine._replicate`` — stable input shardings under the
@@ -546,6 +572,14 @@ class ContinuousEngine:
     @property
     def verify_compiles(self) -> int:
         return self._verify._cache_size()
+
+    @property
+    def packed_gemms(self) -> dict:
+        """Packed-weight GEMM sites this engine's programs have traced, and
+        their packed bytes, by route (``fqt.PACKED_GEMMS``: kernel or
+        dequant).  Read after warm-up: each program's sites count once, a
+        layer scan's body once."""
+        return self._gemms.snapshot()
 
     # ---- host-side plumbing ------------------------------------------------
 

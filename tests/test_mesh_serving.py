@@ -75,13 +75,32 @@ def test_packed_leaf_specs_congruent():
                                        block=16, axis_sizes={"model": tp})
         assert out["packed"] == (None, "model")
         assert pspecs.congruent(out["packed"], out["scales"])
-    # odd output dim: packed size 15 not divisible by 2 -> dropped, named
+    # odd output dim: 30 not divisible by 4 -> dropped, named
     drops = []
     out = pspecs.packed_leaf_specs((None, "model"), (64, 30), axis=-2,
-                                   block=16, axis_sizes={"model": 2},
+                                   block=16, axis_sizes={"model": 4},
                                    path="layers/attn/wq", drops=drops)
     assert out["packed"] == () and out["scales"] == ()
     assert drops and "layers/attn/wq" in drops[0]
+
+
+@pytest.mark.parametrize("tp,shards", [(2, True), (4, True), (8, False)])
+def test_packed_leaf_specs_halved_blocking_axis(tp, shards):
+    """The blocking axis is the halved one: a row-parallel (K, N) weight
+    packs to (K/2, N) codes and (K/16, N) scales, and shards on K only
+    where every shard holds whole blocks (K/16 divisible); N is not
+    halved, so an output dim shards whenever it divides."""
+    K, N = 64, 30
+    drops = []
+    out = pspecs.packed_leaf_specs(("model", None), (K, N), axis=-2,
+                                   block=16, axis_sizes={"model": tp},
+                                   path="layers/attn/wo", drops=drops)
+    assert out["packed"] == (("model",) if shards else ())
+    assert pspecs.congruent(out["packed"], out["scales"])
+    assert bool(drops) != shards
+    col = pspecs.packed_leaf_specs((None, "model"), (K, N), axis=-2,
+                                   block=16, axis_sizes={"model": 2})
+    assert col["packed"] == (None, "model")
 
 
 def test_wire_format_accounting():

@@ -2,12 +2,15 @@
 
 The harness is driven by data.  ``BENCHMARK.json`` names each cell's
 configuration and traffic; ``configs/<config>.json`` holds the sizes as
-run, ``traffic/<traffic>.json`` the traffic parameters, ``cells/<cell>.json``
-the cell's run settings and correctness limits, ``metrics/<metric>.py`` one
-reader per per-layer metric, and ``peaks.json`` the device peaks.
+run, ``arch/<arch>.py`` the model those sizes describe (the configuration
+file's ``"arch"``), ``traffic/<traffic>.json`` the traffic parameters,
+``cells/<cell>.json`` the cell's run settings and correctness limits,
+``metrics/<metric>.py`` one reader per per-layer metric, and
+``peaks.json`` the device peaks.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -19,6 +22,20 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
 OUT = ROOT / ".perfbench_out"          # traces and scratch (git-ignored)
 CACHE = ROOT / ".jax_cache"            # the persistent compile cache
+ARCH = HERE / "arch"                   # one module per architecture
+
+# What an architecture module provides (arch/dense.py is the example), by
+# the traffic kind of the cells that use it.  ``model_of(config)`` gives
+# the hashable sizes, with ``vocab`` and ``layers`` among them; every other
+# function takes those sizes first.
+ARCH_API = {
+    "any": ("model_of", "program_config"),
+    "train": ("make_params", "reference_params", "seq_loss", "train_flops",
+              "train_gemm_flops_executed"),
+    "serve": ("pack_params", "make_embed", "make_layer", "serve_layer",
+              "serve_head", "prefill_flops", "served_flops",
+              "decode_step_bytes"),
+}
 
 
 class NoDevice(SystemExit):
@@ -136,6 +153,30 @@ class Clock:
             self.compiles += 1
         elif event == self.CACHE_READ:
             self.cache_read_s += duration
+
+
+def arch(config: dict, kind: str = "any"):
+    """The architecture module the configuration names, ``arch/<arch>.py``,
+    checked for what cells of traffic ``kind`` call."""
+    mod = _load_arch(ARCH / f"{config['arch']}.py")
+    missing = [n for n in ARCH_API["any"] + ARCH_API[kind]
+               if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"perfbench: arch {config['arch']!r} lacks "
+                         f"{missing}")
+    return mod
+
+
+@functools.cache
+def _load_arch(path: pathlib.Path):
+    """One module object per file, so that its jitted functions keep their
+    compiled programs across the runs of one process."""
+    name = f"perfbench_arch_{path.stem.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(metric: str):

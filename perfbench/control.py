@@ -102,8 +102,8 @@ def main(argv=None):
                    "reference_s": detail["reference_s"]}
             if fault == "none" and seed in args.control_seeds \
                     and secs == short:
-                ctl = serve_cell.control_stats(ctx["model"], key, seqs,
-                                               logits)
+                ctl = serve_cell.control_stats(ctx["arch"], ctx["model"],
+                                               key, seqs, logits)
                 row["control"] = ctl
                 row["control_correct"] = serve_cell.judge(
                     ctl, spec["cell"]["limits"])[1]

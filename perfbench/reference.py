@@ -1,8 +1,8 @@
 """Plain reference for the benchmark's correctness checks.
 
-A straightforward jax.numpy implementation of the cells' model (a dense
-pre-norm decoder: RMSNorm, QKV bias, RoPE, causal softmax attention,
-SwiGLU MLP, untied head) and of its stated numerics:
+A straightforward jax.numpy implementation of the pieces the cells' models
+are made of, and of their stated numerics (each architecture's layers,
+loss and serving head are in ``arch/<name>.py``, built from these):
 
   * every linear layer block-quantizes both operands along the contraction
     axis, NVFP4 (E2M1 codes, one E4M3 scale per 16 values under a
@@ -19,12 +19,12 @@ It imports nothing of the program under test.  Quantized operands are
 exact in bfloat16 (a 2-bit E2M1 significand times a 4-bit E4M3 or E8M0
 scale times a power of two), so the dots feed them as bfloat16 with
 float32 accumulation; the attention's float32 dots run at HIGHEST.
-Weights come from ``weights.py`` (the benchmark's own generator).
+Weights come from ``weights.py`` (the benchmark's own generator) through
+the architecture module.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 
 import jax
@@ -131,7 +131,7 @@ def kv_rows(x, fmt: Fmt):
 # ---- the FQT matmul (the paper's six points) ------------------------------------
 
 
-def _dot(a, b):
+def dot(a, b):
     """a @ b for operands exact in bf16: bf16 in, float32 accumulation."""
     return jnp.matmul(a.astype(BF16), b.astype(BF16),
                       preferred_element_type=F32)
@@ -147,7 +147,7 @@ def qmm(x, w, key, fmt_name):
     output in x's dtype.  Backward: dx = Q_sr(g) Q(w)^T blocked along N,
     dw = Q_sr(x)^T Q_sr(g) blocked along M (the tokens)."""
     f = FORMATS[fmt_name]
-    return _dot(quantize(x, f, -1), quantize(w, f, 0)).astype(x.dtype)
+    return dot(quantize(x, f, -1), quantize(w, f, 0)).astype(x.dtype)
 
 
 def _qmm_fwd(x, w, key, fmt_name):
@@ -157,10 +157,10 @@ def _qmm_fwd(x, w, key, fmt_name):
 def _qmm_bwd(fmt_name, res, g):
     x, w, key = res
     f = FORMATS[fmt_name]
-    dx = _dot(quantize(g, f, -1, _uniform(key, 0, g.shape)),
-              quantize(w, f, 1).T).astype(x.dtype)
-    dw = _dot(quantize(x, f, 0, _uniform(key, 1, x.shape)).T,
-              quantize(g, f, 0, _uniform(key, 2, g.shape))).astype(w.dtype)
+    dx = dot(quantize(g, f, -1, _uniform(key, 0, g.shape)),
+             quantize(w, f, 1).T).astype(x.dtype)
+    dw = dot(quantize(x, f, 0, _uniform(key, 1, x.shape)).T,
+             quantize(g, f, 0, _uniform(key, 2, g.shape))).astype(w.dtype)
     return dx, dw, np.zeros(key.shape, jax.dtypes.float0)
 
 
@@ -169,7 +169,7 @@ qmm.defvjp(_qmm_fwd, _qmm_bwd)
 
 def dense_fwd(x, w, fmt: Fmt):
     """Forward-only quantized linear layer (serving): RtN both operands."""
-    return _dot(quantize(x, fmt, -1), quantize(w, fmt, 0)).astype(x.dtype)
+    return dot(quantize(x, fmt, -1), quantize(w, fmt, 0)).astype(x.dtype)
 
 
 # ---- model pieces -----------------------------------------------------------------
@@ -206,71 +206,7 @@ def attention(q, k, v, qpos, kpos):
     return o.reshape(q.shape[0], H, D)
 
 
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """The sizes the reference needs, read from a configuration file."""
-    d: int
-    heads: int
-    kv_heads: int
-    d_ff: int
-    vocab: int
-    layers: int
-    theta: float
-    eps: float
-
-    @property
-    def hd(self) -> int:
-        return self.d // self.heads
-
-
-# ---- training: loss, gradients, AdamW ----------------------------------------------
-
-
-def _train_layer(m: Model, fmt: str, lp, x, key, head_groups: int):
-    """One decoder layer on one sequence (S, d), every linear through qmm."""
-    S = x.shape[0]
-    pos = jnp.arange(S, dtype=jnp.int32)
-    keys = jax.random.split(key, 7)
-    h = rmsnorm(x, lp["ln1"], m.eps)
-    q = qmm(h, lp["wq"], keys[0], fmt) + lp["bq"]
-    k = qmm(h, lp["wk"], keys[1], fmt) + lp["bk"]
-    v = qmm(h, lp["wv"], keys[2], fmt) + lp["bv"]
-    q = rope(q.reshape(S, m.heads, m.hd), pos, m.theta)
-    k = rope(k.reshape(S, m.kv_heads, m.hd), pos, m.theta)
-    v = v.reshape(S, m.kv_heads, m.hd)
-    # heads in groups (whole KV groups), each group's scores recomputed
-    # in backward
-    g = math.gcd(head_groups, m.kv_heads)
-    qs = q.reshape(S, g, m.heads // g, m.hd).swapaxes(0, 1)
-    ks = k.reshape(S, g, m.kv_heads // g, m.hd).swapaxes(0, 1)
-    vs = v.reshape(S, g, m.kv_heads // g, m.hd).swapaxes(0, 1)
-    att = jax.checkpoint(lambda a, b, c: attention(a, b, c, pos, pos))
-    o = jax.lax.map(lambda t: att(*t), (qs, ks, vs))
-    o = o.swapaxes(0, 1).reshape(S, m.heads * m.hd).astype(x.dtype)
-    x = x + qmm(o, lp["wo"], keys[3], fmt)
-    h = rmsnorm(x, lp["ln2"], m.eps)
-    gt = qmm(h, lp["w_gate"], keys[4], fmt)
-    up = qmm(h, lp["w_up"], keys[5], fmt)
-    act = jax.nn.silu(gt.astype(F32)).astype(gt.dtype) * up
-    return x + qmm(act, lp["w_down"], keys[6], fmt)
-
-
-def seq_loss(m: Model, fmt: str, params, tokens, key, scale,
-             head_groups: int = 8):
-    """Sum of next-token NLL over one sequence, times ``scale`` (1 / all
-    tokens of the batch, so that the per-sequence sums add to the mean)."""
-    x = params["embed"][tokens[:-1]]
-    for i in range(m.layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        x = jax.checkpoint(partial(_train_layer, m, fmt, head_groups=
-                                   head_groups))(
-            lp, x, jax.random.fold_in(key, i))
-    x = rmsnorm(x, params["ln_f"], m.eps)
-    logits = qmm(x, params["lm_head"][:, :m.vocab],
-                 jax.random.fold_in(key, 10_000), fmt)
-    logp = jax.nn.log_softmax(logits.astype(F32), axis=-1)
-    nll = -jnp.take_along_axis(logp, tokens[1:, None], axis=-1)[:, 0]
-    return jnp.sum(nll) * scale
+# ---- training: AdamW and the reference's steps --------------------------------
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,10 +249,11 @@ def adamw(opt: Adam, step, grads, master, mom, vel):
     return grads, master, mom, vel
 
 
-def train_reference(m: Model, fmt: str, opt: Adam, params, batches, key,
-                    head_groups: int = 8):
+def train_reference(seq_loss, opt: Adam, params, batches, key):
     """Run len(batches) training steps of the reference from ``params``
-    (bf16 on the host, the benchmark's weights).  Returns per-step losses,
+    (bf16 on the host, the benchmark's weights), with the architecture's
+    loss ``seq_loss(params, tokens, key, scale)`` of one sequence (the sum
+    of its next-token NLL times ``scale``).  Returns per-step losses,
     the clipped first-step gradient's norm per leaf, the first gradient's
     own norm per leaf, and the norm per leaf of the master weights'
     change.
@@ -326,8 +263,7 @@ def train_reference(m: Model, fmt: str, opt: Adam, params, batches, key,
     master weights rounded to bf16, as stated for the configuration.  The
     device holds only the master weights, the moments, one step's bf16
     weights and the summed gradients."""
-    vg = jax.value_and_grad(partial(seq_loss, m, fmt,
-                                    head_groups=head_groups), argnums=0)
+    vg = jax.value_and_grad(seq_loss, argnums=0)
 
     def acc_grad(acc, weights, tokens, key, scale):
         loss, g = vg(weights, tokens, key, scale)
@@ -365,37 +301,3 @@ def train_reference(m: Model, fmt: str, opt: Adam, params, batches, key,
     change = jax.tree.map(lambda a, b: diff(a, jnp.asarray(b)), master,
                           params)
     return losses, first_g, first_raw, jax.device_get(change)
-
-
-# ---- serving: teacher-forced logits through the KV cache's semantics -----------------
-
-
-def serve_layer(m: Model, fmt: Fmt, kv_fmt: Fmt, lp, x, plen):
-    """One layer over one padded sequence x (T, d) whose first ``plen``
-    positions are the prompt: prompt queries attend the fresh bf16 K/V,
-    later queries every row as the cache stores it."""
-    T = x.shape[0]
-    pos = jnp.arange(T, dtype=jnp.int32)
-    h = rmsnorm(x, lp["ln1"], m.eps)
-    q = dense_fwd(h, lp["wq"], fmt) + lp["bq"]
-    k = dense_fwd(h, lp["wk"], fmt) + lp["bk"]
-    v = dense_fwd(h, lp["wv"], fmt) + lp["bv"]
-    q = rope(q.reshape(T, m.heads, m.hd), pos, m.theta)
-    k = rope(k.reshape(T, m.kv_heads, m.hd), pos, m.theta)
-    v = v.reshape(T, m.kv_heads, m.hd)
-    o_fresh = attention(q, k, v, pos, pos)
-    o_cache = attention(q, kv_rows(k, kv_fmt), kv_rows(v, kv_fmt), pos, pos)
-    o = jnp.where((pos < plen)[:, None, None], o_fresh, o_cache)
-    x = x + dense_fwd(o.reshape(T, -1).astype(x.dtype), lp["wo"], fmt)
-    h = rmsnorm(x, lp["ln2"], m.eps)
-    gt = dense_fwd(h, lp["w_gate"], fmt)
-    up = dense_fwd(h, lp["w_up"], fmt)
-    act = jax.nn.silu(gt.astype(F32)).astype(gt.dtype) * up
-    return x + dense_fwd(act, lp["w_down"], fmt)
-
-
-def serve_head(m: Model, fmt: Fmt, ln_f, lm_head, x):
-    """float32 logits over the vocabulary for rows x (R, d)."""
-    h = rmsnorm(x, ln_f, m.eps)
-    return _dot(quantize(h, fmt, -1),
-                quantize(lm_head[:, :m.vocab], fmt, 0))

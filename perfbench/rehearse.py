@@ -51,15 +51,14 @@ def train(spec, batches):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    import train_cell
-    import weights
     from jax.sharding import Mesh
     from repro.core import fqt, threshold
     from repro.optim import adamw, schedule
     from repro.train.step import TrainConfig, TrainState, make_train_step
     dev = topology()
-    m = train_cell.model_of(spec["config"])
-    cfg = train_cell.program_config(spec["config"])
+    arch = common.arch(spec["config"], "train")
+    m = arch.model_of(spec["config"])
+    cfg = arch.program_config(spec["config"])
     o = spec["cell"]["optimizer"]
     tcfg = TrainConfig(
         opt=adamw.AdamWConfig(lr_peak=o["lr"], weight_decay=o["weight_decay"],
@@ -67,7 +66,7 @@ def train(spec, batches):
         sched=schedule.ScheduleConfig(peak_lr=o["lr"], warmup_steps=0,
                                       total_steps=o["total_steps"]),
         remat=spec["cell"]["remat"])
-    params = jax.eval_shape(lambda: weights.make_params(
+    params = jax.eval_shape(lambda: arch.make_params(
         m, jax.random.PRNGKey(0)))
     state = jax.eval_shape(lambda p: TrainState(
         jnp.zeros((), jnp.int32), p, adamw.init(p, tcfg.opt),
@@ -87,20 +86,19 @@ def train(spec, batches):
 def serve(spec):
     import jax
     import jax.numpy as jnp
-    import serve_cell
-    import train_cell
     from repro.core import fqt
     from repro.distributed import sharding as shd
     from repro.models import registry
     from repro.serve import ContinuousEngine
     from repro.serve.engine import ServeConfig
     dev = topology()
-    m = train_cell.model_of(spec["config"])
-    cfg = train_cell.program_config(spec["config"])
+    arch = common.arch(spec["config"], "serve")
+    m = arch.model_of(spec["config"])
+    cfg = arch.program_config(spec["config"])
     e, t = spec["cell"]["engine"], spec["traffic"]
     pad = -(-t["prompt"]["max"] // e["page_size"]) * e["page_size"]
     qcfg = fqt.qaf_config()
-    params = jax.eval_shape(lambda k: serve_cell.build_packed(
+    params = jax.eval_shape(lambda k: arch.pack_params(
         m, k, qcfg.fwd_w), jax.random.PRNGKey(0))
     scfg = ServeConfig(batch_size=e["slots"], max_len=e["max_len"],
                        kv_cache_format=e["kv_format"],

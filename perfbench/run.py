@@ -43,6 +43,16 @@ def configure_jax():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
+def add_trace(ctx: dict, path) -> None:
+    """What the per-layer readers read of the window's trace file: op kinds
+    and programs (``ctx["trace"]``), device seconds by the program's scopes
+    and idle seconds by its host phases (``ctx["scopes"]``)."""
+    import scopes
+    import xplane
+    ctx["trace"] = xplane.reduce(path)
+    ctx["scopes"] = scopes.reduce(path)
+
+
 def readings(detail: dict) -> dict:
     """Every reading of the correctness check, compared or not."""
     keep = ("readings", "leaves", "stats", "reference_s")
@@ -64,10 +74,18 @@ def run_cell(args):
     else:
         raise SystemExit(f"perfbench: unknown traffic kind {kind!r}")
     if args.trace:
+        import scopes
         import xplane
-        red = xplane.reduce(xplane.find_xplane(tdir))
+        t = common.now()
+        add_trace(ctx, xplane.find_xplane(tdir))
         shutil.rmtree(tdir, ignore_errors=True)
-        ctx["trace"] = red
+        red, sc = ctx["trace"], ctx["scopes"]
+        # device seconds by scope in each program that holds 1% of busy
+        detail["scopes"] = {
+            "by_scope": {p: scopes.by_scope(sc, p) for p, v in
+                         sc.get("module_s", {}).items()
+                         if v >= 0.01 * sc["busy_s"]},
+            "gap_s": sc.get("gap_s"), "parse_s": common.now() - t}
         out["metrics"] = common.read_per_layer(spec, ctx)
         out["device"]["busy_s"] = red.get("busy_s", 0.0)
         out["device"]["window_s"] = ctx["window_s"]
@@ -86,6 +104,9 @@ def main(argv=None) -> int:
     out, checks, detail = run_cell(args)
     print("readings " + json.dumps(readings(detail)), file=sys.stderr,
           flush=True)
+    if "scopes" in detail:
+        print("scopes " + json.dumps(detail["scopes"]), file=sys.stderr,
+              flush=True)
     import jax
     common.memory_note(jax.devices())
     common.emit(out, checks)

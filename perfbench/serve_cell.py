@@ -2,7 +2,8 @@
 
 Set-up makes the weights on the device from the seed and packs them layer
 by layer with the program's quantize-once packer (the whole model in bf16
-does not fit a chip), the way an exported packed artifact is served
+does not fit a chip; ``pack_params`` of the configuration's architecture
+module, ``arch/<arch>.py``), the way an exported packed artifact is served
 (``pack_weights=False``).  It warms both engine programs on a short trace,
 times a tick and a prefill there, and sizes the window's trace in ticks to
 fill ``--seconds``: a backlog at tick 0 and Poisson arrivals per tick, all
@@ -24,42 +25,12 @@ import numpy as np
 
 import common
 import reference as ref
+import scopes
 import traffic as tr
 import weights
-from train_cell import model_of, program_config
 
 FAR = 1.0          # logits: a served token this far below the best is far
 MIN_TOKENS = 8     # a request's own far share counts from this many tokens
-GEMM_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-
-
-def build_packed(m: ref.Model, key, spec):
-    """The packed model, made and packed one layer at a time."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.quantize import pack_quantize
-
-    def pack_layer(k, i):
-        lay = weights.make_layer(m, k, i)
-        return {n: (pack_quantize(v, spec, axis=-2) if n in GEMM_LEAVES
-                    else v) for n, v in lay.items()}
-
-    shapes = jax.eval_shape(pack_layer, key, 0)
-    buf = jax.tree.map(lambda s: jnp.zeros((m.layers,) + s.shape, s.dtype),
-                       shapes)
-
-    def fill(b, k, i):
-        return jax.tree.map(lambda a, x: a.at[i].set(x), b, pack_layer(k, i))
-
-    fill = jax.jit(fill, donate_argnums=0)
-    for i in range(m.layers):
-        buf = fill(buf, key, i)
-    head = jax.jit(lambda k: pack_quantize(weights.make_head(m, k), spec,
-                                           axis=-2))(key)
-    return {"embed": jax.jit(lambda k: weights.make_embed(m, k))(key),
-            "layers": weights.nest(buf),
-            "ln_f": jax.jit(lambda k: weights.make_ln_f(m, k))(key),
-            "lm_head": head}
 
 
 def warm_host_ops(eng, slots: int, chunk: int) -> None:
@@ -135,12 +106,13 @@ def pick_sample(served: dict, seed: int, want_tokens: int, most: int):
     return pick
 
 
-def reference_logits(m: ref.Model, key, seqs, fmt: str, kv_fmt: str,
+def reference_logits(arch, m, key, seqs, fmt: str, kv_fmt: str,
                      group: int = 8):
-    """Teacher-forced reference over ``seqs`` [(prompt, served)], layer by
-    layer, ``group`` sequences at a time.  Returns, per sequence, float32
-    logits at the positions whose next token was served (the last prompt
-    position, then each fed served token)."""
+    """Teacher-forced reference of the architecture module ``arch`` over
+    ``seqs`` [(prompt, served)], layer by layer, ``group`` sequences at a
+    time.  Returns, per sequence, float32 logits at the positions whose
+    next token was served (the last prompt position, then each fed served
+    token)."""
     import jax
     import jax.numpy as jnp
     f, kf = ref.FORMATS[fmt], ref.FORMATS[kv_fmt]
@@ -154,14 +126,15 @@ def reference_logits(m: ref.Model, key, seqs, fmt: str, kv_fmt: str,
         toks[i, :len(full)] = full
         plens[i] = len(p)
     toks, plens = toks.reshape(nb, G, T), plens.reshape(nb, G)
-    embed = jax.jit(lambda k: weights.make_embed(m, k))(key)
+    embed = jax.jit(lambda k: arch.make_embed(m, k))(key)
     x = embed[jnp.asarray(toks)]                     # (nb, G, T, d)
     del embed
 
     @jax.jit
     def layer(x, k, i):
-        lp = weights.make_layer(m, k, i)
-        one = jax.vmap(lambda xs, pl: ref.serve_layer(m, f, kf, lp, xs, pl))
+        lp = arch.make_layer(m, k, i)
+        one = jax.vmap(lambda xs, pl: arch.serve_layer(m, f, kf, lp, xs,
+                                                       pl))
         return jax.lax.map(lambda a: one(*a), (x, jnp.asarray(plens)))
 
     for i in range(m.layers):
@@ -172,8 +145,7 @@ def reference_logits(m: ref.Model, key, seqs, fmt: str, kv_fmt: str,
                            for i, (p, s) in enumerate(seqs)])
     h = x[rows[:, 0], rows[:, 1]]
     del x
-    head = jax.jit(lambda k, h: ref.serve_head(
-        m, f, weights.make_ln_f(m, k), weights.make_head(m, k), h))
+    head = jax.jit(lambda k, h: arch.serve_head(m, f, k, h))
     logits = jnp.concatenate([head(key, h[a:a + 512])
                               for a in range(0, h.shape[0], 512)])
     out, at = [], 0
@@ -207,14 +179,15 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         else jax.devices()
     clock = common.Clock()
     cell, t = spec["cell"], spec["traffic"]
-    m = model_of(spec["config"])
-    cfg = program_config(spec["config"])
+    arch = common.arch(spec["config"], "serve")
+    m = arch.model_of(spec["config"])
+    cfg = arch.program_config(spec["config"])
     e = cell["engine"]
     slots, chunk = e["slots"], e["decode_chunk"]
     pad = -(-t["prompt"]["max"] // e["page_size"]) * e["page_size"]
     qcfg = fqt.qaf_config()
     key = weights.root_key(seed)
-    params = build_packed(m, key, qcfg.fwd_w)
+    params = arch.pack_params(m, key, qcfg.fwd_w)
     want = jax.eval_shape(lambda k: packing.pack_model_params(
         cfg, registry.init_params(cfg, k), qcfg.fwd_w), key)
     is_p = lambda x: isinstance(x, PackedQuantizedTensor)   # noqa: E731
@@ -222,8 +195,9 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
             jax.tree.structure(params, is_leaf=is_p):
         raise SystemExit("perfbench: packed layout differs from the "
                          "program's packed parameter tree")
+    eos = weights.padded_vocab(m.vocab) + 1    # outside the vocabulary
     scfg = ServeConfig(batch_size=slots, max_len=e["max_len"],
-                       temperature=0.0, eos_id=weights.padded_vocab(m) + 1,
+                       temperature=0.0, eos_id=eos,
                        seed=seed % 2 ** 31, kv_cache_format=e["kv_format"],
                        page_size=e["page_size"], max_slots=slots,
                        prefill_len=pad, decode_chunk=chunk)
@@ -283,7 +257,8 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         bad = max(served, key=lambda r: (len(served[r]), -r))
         served[bad] = (served[bad] + 1) % m.vocab
     n_tok = {r: len(v) for r, v in served.items()}
-    walls = tick_walls(eng.tracer.trace_events())
+    events = eng.tracer.trace_events()
+    walls = tick_walls(events)
     # the window: from the start of tick 1 to the end of the last tick
     w0, w1 = walls[1][0], walls[max(walls)][1]
     window_s = w1 - w0
@@ -304,12 +279,14 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
     dev = common.device_info(devs)
     # per-request work for the per-layer readers, over the whole traced run
     prompts = {q.rid: len(q.prompt) for q in reqs}
-    ctx = {"model": m, "slots": slots, "window_s": run_s,
+    ctx = {"arch": arch, "model": m, "slots": slots, "window_s": run_s,
            "compile": compile_setup,
            "peaks": common.peaks(dev["kind"]) if trace else None,
            "occupancy": float(np.mean(met.active_depth[1:])) / slots,
            "prompts": prompts, "served": n_tok,
            "decode_steps": int(sched.stats["decode_steps"])}
+    if trace:
+        ctx["tick_phases"] = scopes.tick_phases(events)
     e2e = {"serve_tokens_per_s": in_window / window_s, "setup_s": setup_s}
     if tpots:
         e2e["tpot_p95_s"] = tr.percentile(tpots, 95)
@@ -324,7 +301,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
                          cell["check"]["most_requests"])
     byid = {q.rid: q for q in reqs}
     seqs = [(np.asarray(byid[r].prompt), served[r]) for r in sample]
-    logits = reference_logits(m, key, seqs, "nvfp4", e["kv_format"])
+    logits = reference_logits(arch, m, key, seqs, "nvfp4", e["kv_format"])
     stats = gap_stats([token_gaps(lg, s) for lg, (_, s) in
                        zip(logits, seqs)])
     checks, correct = judge(stats, cell["limits"])
@@ -367,12 +344,12 @@ def judge(stats: dict, limits: dict) -> tuple:
                        for c in checks.values())
 
 
-def control_stats(m, key, seqs, logits_ref):
+def control_stats(arch, m, key, seqs, logits_ref):
     """The control's readings: the reference in MXFP4 (the next precision
     below NVFP4, weights, activations and cache rows) puts its own best
     token first at every served position; those tokens take the served
     tokens' place and are read as they are (``gap_stats``, then
     ``judge``)."""
-    low = reference_logits(m, key, seqs, "mxfp4", "mxfp4")
+    low = reference_logits(arch, m, key, seqs, "mxfp4", "mxfp4")
     return gap_stats([token_gaps(hi, np.asarray(np.argmax(
         np.asarray(lo), axis=-1))) for lo, hi in zip(low, logits_ref)])

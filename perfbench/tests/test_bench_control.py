@@ -60,7 +60,8 @@ def test_serve_cell_check_and_control():
     assert out["correct"], checks
     # the control's tokens in the served tokens' place, through the same
     # reading and verdict
-    ctl = serve_cell.control_stats(ctx["model"], key, seqs, logits)
+    ctl = serve_cell.control_stats(ctx["arch"], ctx["model"], key, seqs,
+                                   logits)
     _, ok = serve_cell.judge(ctl, SERVE_LIMITS)
     assert not ok, ctl
     # one slot's tokens altered: the worst request shows it

@@ -200,3 +200,27 @@ def test_gaps_by_tick():
     assert len(got) == 2
     assert got[0] == pytest.approx({"engine.tick": 10, "engine.admit": 5})
     assert got[1] == pytest.approx({"engine.tick": 50, "engine.sync": 10})
+
+
+WIRED = ("quant_share.train", "kv_share.decode", "host_ms.serve")
+
+
+@pytest.mark.parametrize("name", WIRED)
+def test_metric_files_read_what_the_readers_read(name):
+    """Each metric file is its reader of ``READERS``, on the context a
+    traced run builds: from the committed trace (no program scopes, None),
+    with a scoped reduction and the engine's tick phases (a number), and
+    with no scopes at all, as before the trace reached the readers."""
+    import common
+    import run
+    read = common.load_reader(name)
+    ctx = {}
+    run.add_trace(ctx, TRACE)
+    assert ctx["scopes"]["busy_s"] == pytest.approx(ctx["trace"]["busy_s"],
+                                                    rel=1e-3)
+    assert read(ctx) is None and scopes.READERS[name](ctx) is None
+    full = dict(ctx, scopes=_synthetic(),
+                tick_phases=scopes.tick_phases(_engine_events()))
+    assert read(full) is not None
+    assert read(full) == scopes.READERS[name](full)
+    assert read({"trace": ctx["trace"]}) is None

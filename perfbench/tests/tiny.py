@@ -2,7 +2,7 @@
 import copy
 
 TRAIN_CONFIG = {
-    "name": "tiny-train", "program_arch": "codeqwen1.5-7b",
+    "name": "tiny-train", "arch": "dense", "program_arch": "codeqwen1.5-7b",
     "program_preset": "smoke", "hidden_size": 128,
     "intermediate_size": 256, "num_hidden_layers": 1,
     "num_attention_heads": 4, "num_key_value_heads": 4, "vocab_size": 512,

@@ -1,6 +1,7 @@
 """A training cell: the program's jitted FQT train step, repeated.
 
-Set-up makes the weights on the device from the seed, builds the
+Set-up makes the weights on the device from the seed (in the layout of
+the configuration's architecture module, ``arch/<arch>.py``), builds the
 program's step (``train/step.py:make_train_step``) and its state, and
 drives that one object through its first three steps on batches 0-2 of
 the program's synthetic stream (``data/pipeline.py``), recording what the
@@ -16,6 +17,7 @@ from __future__ import annotations
 
 import gc
 import math
+from functools import partial
 
 import numpy as np
 
@@ -26,44 +28,11 @@ import weights
 B1 = 0.9
 
 
-def model_of(config: dict) -> ref.Model:
-    c = config
-    return ref.Model(d=c["hidden_size"], heads=c["num_attention_heads"],
-                     kv_heads=c["num_key_value_heads"],
-                     d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-                     layers=c["num_hidden_layers"],
-                     theta=float(c["rope_theta"]), eps=float(c["rms_norm_eps"]))
-
-
-def program_config(config: dict):
-    """The program's ModelConfig for the file: its registered entry, cut
-    with dataclasses.replace (depth and vocabulary), and given the KV
-    heads the file states (the published count, where the registered
-    entry differs); every other width must agree with the file."""
-    import dataclasses
-    from repro.configs import get_config
-    base = get_config(config["program_arch"])
-    if config.get("program_preset") == "smoke":     # CPU tests only
-        base = base.smoke()
-    cfg = dataclasses.replace(base, n_layers=config["num_hidden_layers"],
-                              vocab_size=config["vocab_size"],
-                              n_kv_heads=config["num_key_value_heads"])
-    m = model_of(config)
-    got = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.hd,
-           cfg.rope_theta, cfg.norm_eps, cfg.qkv_bias, cfg.act,
-           cfg.tie_embeddings, cfg.family)
-    want = (m.d, m.heads, m.kv_heads, m.d_ff, m.hd, m.theta, m.eps,
-            bool(config["attention_bias"]), "swiglu",
-            bool(config["tie_word_embeddings"]), "dense")
-    if got != want:
-        raise SystemExit(f"perfbench: program config {got} differs from "
-                         f"the configuration file {want}")
-    return cfg
-
-
 def leaf_name(path) -> str:
+    """A leaf by its outermost and innermost key (``layers.<leaf>``), the
+    same in the program's tree and the reference's view of it."""
     keys = [str(getattr(p, "key", getattr(p, "name", p))) for p in path]
-    return "layers." + keys[-1] if keys[0] == "layers" else keys[-1]
+    return ".".join([keys[0], keys[-1]] if len(keys) > 1 else keys)
 
 
 def named(tree) -> dict:
@@ -98,8 +67,9 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         else jax.devices()
     clock = common.Clock()
     cell, traffic = spec["cell"], spec["traffic"]
-    m = model_of(spec["config"])
-    cfg = program_config(spec["config"])
+    arch = common.arch(spec["config"], "train")
+    m = arch.model_of(spec["config"])
+    cfg = arch.program_config(spec["config"])
     B, S = traffic["batch"], traffic["seq_len"]
     o = cell["optimizer"]
     quant = quant or cell["quant"]
@@ -115,7 +85,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         remat=cell["remat"])
 
     key = weights.root_key(seed)
-    params = weights.make_params(m, key)
+    params = arch.make_params(m, key)
     want = jax.eval_shape(lambda k: registry.init_params(cfg, k), key)
     if jax.tree.structure(want) != jax.tree.structure(params) or any(
             a.shape != b.shape or a.dtype != b.dtype for a, b in
@@ -150,7 +120,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)))), t))
     change_norms = jax.jit(lambda master, k: leaf_norms(jax.tree.map(
         lambda a, b: a - b.astype(jnp.float32), master,
-        weights.make_params(m, k))))
+        arch.make_params(m, k))))
 
     # set-up: the first three steps, read for the check
     n_check = 3
@@ -199,8 +169,8 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
 
     # the reference: three steps from the same weights and batches
     t_ref = common.now()
-    ref_params = jax.device_get(weights.make_params(m, key))
-    ref_params = {**ref_params, "layers": weights.flat_layers(ref_params)}
+    ref_params = arch.reference_params(
+        jax.device_get(arch.make_params(m, key)))
     batches = [data.batch(j)["tokens"] for j in range(n_check)]
     adam = ref.Adam(lr=o["lr"], b1=B1, b2=o["beta2"], eps=o["eps"],
                     wd=o["weight_decay"], clip=o["clip_norm"])
@@ -208,7 +178,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
         raise SystemExit("perfbench: the reference runs a constant rate; "
                          "set warmup_steps 0")
     r_losses, r_g, r_graw, r_d = ref.train_reference(
-        m, cell["quant"], adam, ref_params, batches,
+        partial(arch.seq_loss, m, cell["quant"]), adam, ref_params, batches,
         jax.random.fold_in(key, 0x5EED))
     del ref_params
     reference_s = common.now() - t_ref
@@ -236,8 +206,9 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float,
               "reference_s": reference_s}
 
     tokens = steps * B * S
-    ctx = {"model": m, "batch": B, "seq": S, "remat": cell["remat"],
-           "window_s": window_s, "steps": steps, "compile": compile_setup,
+    ctx = {"arch": arch, "model": m, "batch": B, "seq": S,
+           "remat": cell["remat"], "window_s": window_s, "steps": steps,
+           "compile": compile_setup,
            "peaks": common.peaks(dev["kind"]) if trace else None}
     e2e = {"train_tokens_per_s": tokens / window_s, "setup_s": setup_s}
     out = {"correct": bool(correct), "attempted": steps, "failed": failed,
